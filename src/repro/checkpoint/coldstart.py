@@ -21,7 +21,7 @@ module overlaps them so the total approaches the MAX:
    interleave instead of serializing into phases;
 3. **overlapped device upload** — whichever pool thread completes a leaf
    dispatches its ``jax.device_put`` (and, for quantized-u8 leaves
-   restoring onto a single device, the fused Pallas ``dequant_rows`` —
+   restoring onto a single device, the fused Pallas ``dequant_u8`` —
    uint8 crosses the link, floats materialize device-side exactly as the
    device feed plane does for batches) WITHOUT blocking, while later
    leaves are still being fetched/decoded; one quiet barrier at the end
@@ -294,19 +294,24 @@ def _entry_quant_hint(entry: Dict[str, Any]) -> Any:
         return None
 
 
-def _start_warmup(plans: List[_LeafPlan], interpret: Optional[bool]) -> Optional[threading.Thread]:
-    """Populate the jit cache for every unique quantized (shape, dtype)
-    OVERLAPPED with the first fetches: interpret-mode Pallas compiles cost
-    real time, and paying them inside the upload thread would serialize
-    them behind the pipeline instead of hiding them under I/O. The caller
-    must join the returned thread before returning (a compile torn down
-    mid-flight at interpreter exit aborts the process)."""
+def _start_warmup(
+    plans: List[_LeafPlan], interpret: Optional[bool]
+) -> Optional[Tuple[threading.Thread, List[BaseException]]]:
+    """Compile the dequant kernel for every unique quantized (shape, dtype)
+    OVERLAPPED with the first fetches: Pallas compiles cost real time, and
+    paying them inside the upload thread would serialize them behind the
+    pipeline instead of hiding them under I/O. Returns the thread and the
+    list its compile error lands in; the caller joins the thread before
+    returning (a compile torn down mid-flight at interpreter exit aborts
+    the process) and re-raises the error, so a kernel the device refuses
+    fails the restore."""
     shapes = {}
     for p in plans:
         if p.quant is not None and p.sharding is None and p.want:
             shapes[(p.want, str(p.quant.orig_dtype))] = None
     if not shapes:
         return None
+    errors: List[BaseException] = []
 
     def run() -> None:
         try:
@@ -317,27 +322,25 @@ def _start_warmup(plans: List[_LeafPlan], interpret: Optional[bool]) -> Optional
 
             for shape, dt in shapes:
                 c = int(shape[-1])
-                rows = 1
-                for d in shape[:-1]:
-                    rows *= int(d)
-                br = max(256, -(-max(rows, 1) // 8))  # dequant_rows' sizing
-                # AOT lower+compile only: executing a full-size dummy would
-                # burn a leaf's worth of CPU and park this thread in
-                # block_until_ready, GIL-convoying against the fetch wave
+                # AOT lower+compile only, through the same jit entry (and so
+                # the same tile sizing) as the upload path: executing a
+                # full-size dummy would burn a leaf's worth of device time
+                # and park this thread in block_until_ready, GIL-convoying
+                # against the fetch wave
                 ops.dequant_u8.lower(
                     jax.ShapeDtypeStruct(shape, jnp.uint8),
                     jax.ShapeDtypeStruct((c,), jnp.float32),
                     jax.ShapeDtypeStruct((c,), jnp.float32),
-                    out_dtype=jnp.dtype(dt), block_rows=br, interpret=interpret,
+                    out_dtype=jnp.dtype(dt), interpret=interpret,
                 ).compile()
-        except Exception:
-            pass  # warmup is best-effort; the real call surfaces errors
+        except BaseException as e:  # noqa: BLE001 — re-raised by restore_pipelined
+            errors.append(e)
 
     # ralint: allow=thread-lifecycle -- returned to restore_pipelined, which
-    # joins it in its finally block; best-effort warmup with a bounded body
+    # joins it in its finally block; bounded body (one compile per shape)
     t = threading.Thread(target=run, daemon=True, name="ra-coldstart-warm")
     t.start()
-    return t
+    return t, errors
 
 
 def restore_pipelined(
@@ -419,7 +422,7 @@ def restore_pipelined(
                 st.logical_bytes += elems * out_itemsize
                 plan.cost = elems * (out_itemsize if sh is not None else 1)
             else:
-                logical = int(getattr(like, "nbytes", elems))
+                logical = elems * np.dtype(like.dtype).itemsize
                 st.logical_bytes += logical
                 plan.cost = max(logical, 1)
             plans.append(plan)
@@ -430,7 +433,7 @@ def restore_pipelined(
 
     # ---- wave 1: pin versions + prewarm sockets (overlapped) --------------
     t0 = time.perf_counter()
-    warmup: Optional[threading.Thread] = None
+    warmup: Optional[Tuple[threading.Thread, List[BaseException]]] = None
     # a thread that finishes a leaf wakes the scheduler / dispatches H2D
     # through the GIL, and CPython's default 5ms switch interval is the
     # latency of every such wake while the pool grinds task wrappers — at
@@ -546,7 +549,7 @@ def restore_pipelined(
                         # an explicit target needs explicit puts
                         scale = jax.device_put(scale, device)
                         bias = jax.device_put(bias, device)
-                    out = ops.dequant_rows(
+                    out = ops.dequant_u8(
                         moved, scale, bias,
                         out_dtype=np.dtype(plan.quant.orig_dtype), interpret=interpret,
                     )
@@ -655,7 +658,7 @@ def restore_pipelined(
     finally:
         sys.setswitchinterval(prev_switch)
         if warmup is not None:
-            warmup.join()
+            warmup[0].join()
         for p in plans:
             if p.fd is not None:
                 try:
@@ -663,6 +666,8 @@ def restore_pipelined(
                 except OSError:
                     pass
 
+    if warmup is not None and warmup[1]:
+        raise warmup[1][0]
     st.peak_inflight_bytes = budget.peak
     st.restore_s = time.perf_counter() - t_all
 
@@ -735,7 +740,7 @@ def restore_naive(
                 from ..kernels import ops  # deferred: pallas is heavy
 
                 scale, bias = quant.channel_params(int(arr.shape[-1]))
-                out = ops.dequant_rows(
+                out = ops.dequant_u8(
                     jax.device_put(arr, device),
                     jax.device_put(scale, device), jax.device_put(bias, device),
                     out_dtype=np.dtype(quant.orig_dtype), interpret=interpret,

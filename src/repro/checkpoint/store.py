@@ -47,6 +47,7 @@ import jax
 import numpy as np
 
 from .. import core as ra
+from ..core.dtypes import is_float
 
 MANIFEST = "manifest.json"
 _SEP = "__"
@@ -159,7 +160,7 @@ def save_checkpoint(
         if (
             quantize is not None
             and arr.dtype.names is None
-            and np.issubdtype(arr.dtype, np.floating)
+            and is_float(arr.dtype)
             and arr.ndim >= 1
         ):
             # calibrate on the save thread (cheap vs compression) so the

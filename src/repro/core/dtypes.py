@@ -54,6 +54,13 @@ def eltype_of(dtype: np.dtype) -> Tuple[int, int]:
     raise RawArrayError(f"dtype {dtype} has no RawArray element type")
 
 
+def is_float(dtype) -> bool:
+    """True for IEEE floats and for bfloat16, which numpy does not count
+    as ``np.floating`` (it is an ``ml_dtypes`` extension type)."""
+    dtype = np.dtype(dtype)
+    return dtype.kind == "f" or (_HAVE_ML_DTYPES and dtype == _BFLOAT16)
+
+
 def dtype_of(eltype: int, elbyte: int, *, big_endian: bool = False) -> np.dtype:
     """Return the numpy dtype for an ``(eltype, elbyte)`` pair."""
     order = ">" if big_endian else "<"

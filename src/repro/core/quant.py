@@ -12,8 +12,9 @@ floating-point values::
 axis, and reconstruction is the affine map ``x ≈ q * scale + bias``
 computed in float32 — exactly what the fused Pallas kernel
 (``repro.kernels.ops.dequant_u8``) evaluates on device, so the host
-(numpy) and device (Pallas) decode paths agree bit-for-bit on CPU
-interpret mode and within float32 rounding on real accelerators.
+(numpy) and device (Pallas) decode paths agree bit for bit on a TPU; on
+the CPU backend XLA may fuse the multiply-add, which can move the last
+bit of a bfloat16 result.
 
 The schema is deliberately tiny and self-contained: any RawArray reader
 that understands JSON can decode a quantized file, and readers that don't
@@ -29,6 +30,7 @@ from typing import Any, Dict, Optional, Tuple, Union
 
 import numpy as np
 
+from .dtypes import is_float
 from .spec import RawArrayError
 
 # the metadata key the schema lives under (shared with dataset manifests)
@@ -166,7 +168,7 @@ def quant_params(arr: np.ndarray, mode: str = "u8") -> QuantInfo:
     if mode not in _MODES:
         raise RawArrayError(f"unknown quantization mode {mode!r}")
     a = np.asarray(arr)
-    if not np.issubdtype(a.dtype, np.floating):
+    if not is_float(a.dtype):
         raise RawArrayError(f"can only quantize float arrays, got {a.dtype}")
     if a.ndim < 1:
         raise RawArrayError("cannot quantize a 0-d array (no channel axis)")

@@ -41,6 +41,7 @@ import numpy as np
 from .. import core as ra
 from ..core import codec as chunked_codec
 from ..core import engine
+from ..core.dtypes import is_float
 
 MANIFEST = "manifest.json"
 
@@ -109,7 +110,7 @@ class DatasetBuilder:
             if name not in fields:
                 raise ra.RawArrayError(f"quantize names unknown field {name!r}")
             shape, dtype = fields[name]
-            if not np.issubdtype(np.dtype(dtype), np.floating):
+            if not is_float(dtype):
                 raise ra.RawArrayError(
                     f"quantize: field {name!r} is {dtype}, only float fields "
                     f"can be stored quantized"

@@ -76,7 +76,6 @@ class DeviceLoader:
         bufs: Optional[int] = None,
         device: Any = None,
         interpret: Optional[bool] = None,
-        block_rows: Optional[int] = None,
         global_arrays: Optional[bool] = None,
     ):
         import jax  # deferred: keep `repro.data` importable without jax
@@ -111,7 +110,6 @@ class DeviceLoader:
         self.bufs = max(1, bufs if bufs is not None else default_device_bufs())
         self.device = device
         self._interpret = interpret
-        self._block_rows = block_rows
         self._quant_dev: Dict[str, Tuple[Any, Any, np.dtype]] = {}
         # Regression note (ralint guarded-by): the feeder thread writes the
         # h2d_* counters while the consumer writes _wait_s/_n_batches and
@@ -270,10 +268,10 @@ class DeviceLoader:
                 from ..kernels import ops  # deferred: pallas import is heavy
 
                 shards = [
-                    ops.dequant_rows(
+                    ops.dequant_u8(
                         s, *self._quant_params_on(d)[k][:2],
                         out_dtype=self._quant_params_on(d)[k][2],
-                        block_rows=self._block_rows, interpret=self._interpret,
+                        interpret=self._interpret,
                     )
                     for s, d in zip(shards, devs)
                 ]
@@ -299,9 +297,9 @@ class DeviceLoader:
 
         for f, (scale, bias, out_dtype) in quant.items():
             if f in moved:
-                moved[f] = ops.dequant_rows(
+                moved[f] = ops.dequant_u8(
                     moved[f], scale, bias, out_dtype=out_dtype,
-                    block_rows=self._block_rows, interpret=self._interpret,
+                    interpret=self._interpret,
                 )
 
     def __next__(self) -> Dict[str, Any]:

@@ -6,6 +6,8 @@ Moments are stored per the model config's ``opt_moment_dtype``:
 * ``int8``    — blockwise-quantized moments (block 128 along the trailing
   axis, absmax scaling), the 8-bit-Adam trick that brings deepseek-v3 /
   kimi-k2 optimizer state under the 16 GiB/chip HBM budget (DESIGN.md §3).
+  The second moment rounds UP: rounded to nearest, its small entries in a
+  block decode as 0, and their update divides by ``eps`` alone.
 
 Optimizer state shards exactly like its parameter (same tree structure),
 so partition specs map 1:1.
@@ -45,13 +47,15 @@ def _pad_to_block(x: jax.Array) -> Tuple[jax.Array, int]:
     return x, n
 
 
-def quantize_blockwise(x: jax.Array) -> Dict[str, jax.Array]:
-    """int8 absmax quantization over trailing-axis blocks of 128."""
+def quantize_blockwise(x: jax.Array, round_up: bool = False) -> Dict[str, jax.Array]:
+    """int8 absmax quantization over trailing-axis blocks of 128;
+    ``round_up`` never decodes below ``x`` (for non-negative ``x``)."""
     xp, _ = _pad_to_block(x.astype(jnp.float32))
     blocks = xp.reshape(*xp.shape[:-1], -1, BLOCK)
     scale = jnp.max(jnp.abs(blocks), axis=-1, keepdims=True) / 127.0
     scale = jnp.maximum(scale, 1e-12)
-    q = jnp.clip(jnp.round(blocks / scale), -127, 127).astype(jnp.int8)
+    rounded = jnp.ceil(blocks / scale) if round_up else jnp.round(blocks / scale)
+    q = jnp.clip(rounded, -127, 127).astype(jnp.int8)
     return {"q": q.reshape(xp.shape), "scale": scale[..., 0]}
 
 
@@ -125,7 +129,7 @@ def apply_updates(
             u = u + cfg.weight_decay * p.astype(jnp.float32)
         p2 = (p.astype(jnp.float32) - lr * u).astype(p.dtype)
         m2 = quantize_blockwise(m_f) if q_leaf else m_f
-        v2 = quantize_blockwise(v_f) if q_leaf else v_f
+        v2 = quantize_blockwise(v_f, round_up=True) if q_leaf else v_f
         return p2, m2, v2
 
     def upd_maybe_scanned(p, g, m, v):

@@ -8,13 +8,14 @@ interpret mode is the validation vehicle).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from .decode_attention import decode_attention_fwd
-from .dequant_u8 import dequant_u8_fwd
+from .dequant_u8 import block_shape, dequant_u8_fwd
 from .flash_attention import flash_attention_fwd
 from .ssd_scan import ssd_scan_fwd
 
@@ -62,26 +63,28 @@ def ssd_scan(x, dtA, Bm, Cm, *, chunk: int = 128, interpret: Optional[bool] = No
     )
 
 
-@functools.partial(jax.jit, static_argnames=("out_dtype", "block_rows", "interpret"))
-def dequant_u8(x, scale, bias, *, out_dtype=jnp.float32, block_rows: int = 256, interpret: Optional[bool] = None):
-    """x (..., C) uint8 -> (..., C) float, fused (x*scale + bias)."""
+@functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
+def dequant_u8(x, scale, bias, *, out_dtype=jnp.float32, interpret: Optional[bool] = None):
+    """x (..., C) uint8 -> (..., C) float, fused (x*scale + bias).
+
+    Tiles come from ``dequant_u8.block_shape``, so every caller of one
+    shape (device feed, cold-start restore, its warmup) compiles the same
+    kernel. A narrow last axis (C not a multiple of 128, e.g. RGB pixels)
+    is repacked to lcm(C, 128) lanes with the channel params tiled to
+    match, when the element count allows it; the result is unchanged."""
     shape = x.shape
-    x2 = x.reshape(-1, shape[-1])
+    if x.size == 0:
+        return jnp.zeros(shape, out_dtype)
+    C = shape[-1]
+    width = math.lcm(C, 128)
+    if C % 128 and x.size % width == 0:
+        reps = width // C
+        x2, scale, bias = x.reshape(-1, width), jnp.tile(scale, reps), jnp.tile(bias, reps)
+    else:
+        x2 = x.reshape(-1, C)
+    block = block_shape(x2.shape[0], x2.shape[1], out_dtype)
     out = dequant_u8_fwd(
-        x2, scale, bias, out_dtype=out_dtype,
-        block_rows=min(block_rows, x2.shape[0]), interpret=_auto_interpret(interpret)
+        x2, scale, bias, out_dtype=out_dtype, block=block,
+        interpret=_auto_interpret(interpret),
     )
     return out.reshape(shape)
-
-
-def dequant_rows(x, scale, bias, *, out_dtype=jnp.float32, block_rows: Optional[int] = None, interpret: Optional[bool] = None):
-    """``dequant_u8`` with an auto-sized grid: when ``block_rows`` is None
-    the row blocks are sized so the grid has ~8 tiles — fewer, larger tiles
-    amortize per-block overhead (interpret mode especially). Shared by the
-    device feed plane and the cold-start restore engine so both pick
-    identical kernel variants (one jit cache entry per shape family)."""
-    rows = 1
-    for d in x.shape[:-1]:
-        rows *= int(d)
-    br = block_rows or max(256, -(-max(rows, 1) // 8))
-    return dequant_u8(x, scale, bias, out_dtype=out_dtype, block_rows=br, interpret=interpret)

@@ -24,9 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x exposes this as TPUCompilerParams; newer jax as CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _kernel(x_ref, dta_ref, b_ref, c_ref, y_ref, state_ref, *, n_chunks, chunk):
     ci = pl.program_id(2)
@@ -95,7 +92,7 @@ def ssd_scan_fwd(
         out_specs=pl.BlockSpec((1, 1, chunk, P), lambda b, h, ci: (b, h, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, L, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

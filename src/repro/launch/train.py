@@ -56,9 +56,11 @@ def main(argv=None) -> int:
     from repro.configs import get_config
     from repro.data import DataLoader, RaDataset, make_token_dataset
     from repro.distributed.optimizer import AdamWConfig
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import build_model
     from repro.train import TrainLoopConfig, train
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     os.makedirs(args.workdir, exist_ok=True)
     ds_root = args.dataset or os.path.join(args.workdir, "dataset")
@@ -95,7 +97,8 @@ def main(argv=None) -> int:
             steps=args.steps,
             ckpt_every=args.ckpt_every,
             ckpt_dir=os.path.join(args.workdir, "ckpt"),
-            adamw=AdamWConfig(lr=args.lr, warmup_steps=20, total_steps=max(args.steps, 200)),
+            adamw=AdamWConfig(lr=args.lr, warmup_steps=20, total_steps=max(args.steps, 200),
+                              moment_dtype=cfg.opt_moment_dtype),
         ),
         resume=not args.fresh,
         restore_mode=args.restore,

@@ -9,6 +9,7 @@ RawArray checkpoint store (one leaf = one .ra file).
 from __future__ import annotations
 
 import math
+import zlib
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -27,7 +28,8 @@ class Initializer:
         self.dtype = dtype
 
     def _fold(self, path: str) -> jax.Array:
-        h = jnp.uint32(abs(hash(path)) % (2**31))
+        # crc32, not hash(): str hashes are salted per process
+        h = jnp.uint32(zlib.crc32(path.encode()) % (2**31))
         return jax.random.fold_in(self.key, h)
 
     def normal(self, path: str, shape, scale: float = 0.02) -> jax.Array:

@@ -53,9 +53,6 @@ def train(
     cfg: ModelConfig = model.cfg
     adamw = loop_cfg.adamw
 
-    params = model.init(jax.random.PRNGKey(init_rng))
-    opt_state = optim.init_state(params, adamw)
-
     if step_fn is None:
 
         def _step(params, opt_state, batch):
@@ -69,12 +66,20 @@ def train(
 
     cm = CheckpointManager(loop_cfg.ckpt_dir, keep=loop_cfg.keep)
     start_step = 0
-    if resume and cm.latest() is not None:
-        s = cm.latest()
+    s = cm.latest() if resume else None
+    if s is None:
+        # jitted: eager init dispatches faster than the device runs it, and
+        # every queued op's output is allocated at dispatch
+        params = jax.jit(model.init)(jax.random.PRNGKey(init_rng))
+        opt_state = jax.jit(lambda p: optim.init_state(p, adamw))(params)
+    else:
+        # restore targets are shapes only: the device holds the state once
+        params_like = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(init_rng)))
+        opt_like = jax.eval_shape(lambda p: optim.init_state(p, adamw), params_like)
         # overlapped cold-start restore straight to device (DESIGN.md §13);
         # restore_mode="naive" keeps the phase-by-phase baseline reachable
         restore_fn = restore_pipelined if restore_mode == "pipelined" else restore_naive
-        params, opt_state, extra = restore_fn(cm.path(s), params, opt_state)
+        params, opt_state, extra = restore_fn(cm.path(s), params_like, opt_like)
         if "loader" in extra:
             loader.restore(LoaderState.from_dict(extra["loader"]))
         start_step = s

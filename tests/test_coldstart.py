@@ -348,3 +348,44 @@ def test_racat_inspect_checkpoint(tmp_path, capsys):
     assert "param__w" in out
     assert "param__inner__b" in out
     assert "u8" in out  # quant schema surfaced
+
+
+# ------------------------------------------------ bf16 leaves, warmup errors
+def test_bf16_leaves_quantize_and_restore_in_bound(tmp_path):
+    """bfloat16 (an ml_dtypes type numpy does not call floating) is stored
+    as u8 codes like any float leaf and restores as bf16 within the
+    quantization bound."""
+    import jax.numpy as jnp
+
+    tree = {k: v.astype(jnp.bfloat16) for k, v in _tree(3).items() if k == "w"}
+    p = save_checkpoint(str(tmp_path), 1, tree, chunked=True, quantize="u8")
+    with open(os.path.join(p, "manifest.json")) as f:
+        entry = json.load(f)["leaves"]["param__w"]
+    assert entry["stored_dtype"] == "uint8" and entry["quant"]["orig_dtype"] == "bfloat16"
+    got, _, _ = restore_pipelined(p, _like(tree))
+    w = np.asarray(got["w"])
+    assert w.dtype == np.dtype(jnp.bfloat16)
+    scale = np.asarray(entry["quant"]["scale"], np.float32)
+    err = np.abs(w.astype(np.float32) - tree["w"].astype(np.float32))
+    # half a code step, plus bf16 rounding of the dequantized value
+    assert (err <= scale / 2 + np.abs(tree["w"].astype(np.float32)) / 128).all()
+
+
+def test_warmup_compile_error_fails_the_restore(tmp_path, monkeypatch):
+    """A dequant kernel the device refuses must fail the restore, not be
+    swallowed by the overlapped warmup."""
+    from repro.kernels import ops
+
+    real = ops.dequant_u8
+
+    class Refusing:
+        def __call__(self, *a, **kw):
+            return real(*a, **kw)
+
+        def lower(self, *a, **kw):
+            raise NotImplementedError("Unsupported cast: uint8 -> float32")
+
+    p = save_checkpoint(str(tmp_path), 1, _tree(4), quantize="u8")
+    monkeypatch.setattr(ops, "dequant_u8", Refusing())
+    with pytest.raises(NotImplementedError, match="Unsupported cast"):
+        restore_pipelined(p, _like(_tree(4)))

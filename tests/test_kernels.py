@@ -106,3 +106,45 @@ def test_dequant_sweep(rows, C, out_dtype):
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(want, np.float32), rtol=2e-2, atol=2e-2
     )
+
+
+@pytest.mark.parametrize("rows,cols,out_dtype", [
+    (50304, 2048, jnp.bfloat16),   # more rows than one tile takes
+    (20, 2048, jnp.float32),       # fewer rows than one u8 tile: all of them
+    (4096, 384, jnp.float32),      # repacked RGB pixels
+    (1, 1 << 22, jnp.float32),     # one row over the budget: lanes tiled
+    (64, 1 << 20, jnp.bfloat16),
+])
+def test_dequant_block_shape_rules(rows, cols, out_dtype):
+    from repro.kernels.dequant_u8 import VMEM_BUDGET, block_shape
+
+    br, bc = block_shape(rows, cols, out_dtype)
+    assert br == rows or br % 32 == 0
+    assert bc == cols or bc % 128 == 0
+    per_elem = 2 + 2 * jnp.dtype(out_dtype).itemsize + 8
+    assert max(br, 32) * -(-bc // 128) * 128 * per_elem <= VMEM_BUDGET
+    if bc < cols:  # lanes are tiled only when one 32-row stripe cannot fit
+        assert 32 * -(-cols // 128) * 128 * per_elem > VMEM_BUDGET
+
+
+@pytest.mark.parametrize("rows,cols", [(70, 1000), (3, 4096)])
+def test_dequant_tiled_lanes_match_ref(rows, cols):
+    """Lane-tiled blocks, with partial edge blocks on both axes, still give
+    the reference's values."""
+    from repro.kernels.dequant_u8 import dequant_u8_fwd
+
+    block = (min(rows, 32), 256)
+    x = jnp.asarray(_rng.integers(0, 256, (rows, cols)), jnp.uint8)
+    scale, bias = _arr(cols, scale=0.01), _arr(cols)
+    out = dequant_u8_fwd(x, scale, bias, block=block, interpret=True)
+    want = ref.dequant_u8_ref(x, scale, bias)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-6, atol=1e-6)
+
+
+def test_dequant_narrow_channels_repacked():
+    """(…, 3) pixels go through 384-lane rows with tiled channel params."""
+    x = jnp.asarray(_rng.integers(0, 256, (4, 32, 32, 3)), jnp.uint8)
+    scale, bias = _arr(3, scale=0.01), _arr(3)
+    out = dequant_u8(x, scale, bias)
+    want = ref.dequant_u8_ref(x.reshape(-1, 3), scale, bias).reshape(x.shape)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-6, atol=1e-6)

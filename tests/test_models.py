@@ -149,3 +149,29 @@ def test_ssd_chunk_invariance():
         outs.append(np.asarray(l))
     np.testing.assert_allclose(outs[0], outs[1], rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(outs[0], outs[2], rtol=1e-4, atol=1e-5)
+
+
+def test_init_is_the_same_in_every_process():
+    """Weights made from a seed must not depend on the process's str-hash
+    salt (PYTHONHASHSEED): a checkpoint, a reference and a restarted job
+    all rebuild the same model."""
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+    code = (
+        "import jax, numpy as np\n"
+        "from repro.configs import get_config\n"
+        "from repro.models import build_model\n"
+        "p = build_model(get_config('olmo_1b').reduced()).init(jax.random.PRNGKey(0))\n"
+        "print(sum(float(np.abs(np.asarray(x, np.float64)).sum()) for x in jax.tree_util.tree_leaves(p)))\n"
+    )
+    sums = {
+        subprocess.run(
+            [sys.executable, "-c", code], capture_output=True, text=True, timeout=120, check=True,
+            env=dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu", PYTHONHASHSEED=str(salt)),
+        ).stdout.split()[-1]
+        for salt in (1, 2)
+    }
+    assert len(sums) == 1, sums

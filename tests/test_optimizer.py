@@ -79,3 +79,35 @@ def test_grad_clip_bounds_update():
     p2, _, info = optim.apply_updates(params, huge, state, cfg)
     assert float(info["grad_norm"]) > 1e8
     assert float(jnp.max(jnp.abs(p2["w"]))) < 1.0  # clipped step stays sane
+
+
+def test_int8_second_moment_never_decodes_below_its_value():
+    """Rounded to nearest, a small v in a block with a large one decoded as
+    0 and the next update divided by eps alone; rounded up it cannot."""
+    v = jnp.asarray([[1e-8, 3e-6, 1e-4] + [0.0] * 125], jnp.float32)
+    up = optim.dequantize_blockwise(optim.quantize_blockwise(v, round_up=True), 128)
+    assert bool(jnp.all(up >= v))
+    assert bool(jnp.all((up == 0) == (v == 0)))
+
+
+def test_int8_moments_track_fp32_on_a_transformer(tmp_path):
+    """A reduced OLMo-1B trained a few steps at a real learning rate: the
+    int8-moment loss stays with the float32 one (round-to-nearest second
+    moments blew it from 6.7 to 184 in six steps)."""
+    from repro.configs import get_config
+    from repro.data import DataLoader, RaDataset, make_token_dataset
+    from repro.models import build_model
+    from repro.train import TrainLoopConfig, train
+
+    cfg = get_config("olmo_1b").reduced().with_(vocab=512)
+    root = str(tmp_path / "ds")
+    make_token_dataset(root, n_docs=64, seq_len=64, vocab=512, shard_rows=64)
+    losses = {}
+    for mt in ("float32", "int8"):
+        loop = TrainLoopConfig(
+            steps=6, ckpt_every=100, ckpt_dir=str(tmp_path / mt), log_every=1000,
+            adamw=optim.AdamWConfig(lr=1e-3, warmup_steps=2, moment_dtype=mt),
+        )
+        losses[mt] = train(build_model(cfg), DataLoader(RaDataset(root), 8, seed=1), loop,
+                           resume=False)["losses"]
+    np.testing.assert_allclose(losses["int8"], losses["float32"], rtol=2e-2)

@@ -126,3 +126,55 @@ def test_loader_prefetch_overlaps(dataset):
     waited = loader.stats()["loader_wait_s"]
     loader.stop()
     assert waited < 0.05
+
+
+def test_resume_restores_into_shapes_not_device_buffers(dataset, tmp_path):
+    """With a checkpoint present, train() initialises nothing on the device:
+    the restore targets come from jax.eval_shape, so resuming holds one
+    copy of the training state, not two."""
+    ck = str(tmp_path / "ck")
+    model = build_model(TINY)
+    train(model, DataLoader(RaDataset(dataset), 8, seed=4), _loop(ck, 5), resume=False)
+
+    concrete_inits = []
+    real_init = model.init
+
+    def spy(key):
+        concrete_inits.append(not isinstance(key, jax.core.Tracer))
+        return real_init(key)
+
+    model.init = spy
+    out = train(model, DataLoader(RaDataset(dataset), 8, seed=4), _loop(ck, 7))
+    assert out["steps"] == 7 and len(out["losses"]) == 2
+    assert concrete_inits and not any(concrete_inits)
+
+
+def test_compile_cache_dir_is_fixed_or_from_env(monkeypatch):
+    from repro.launch import compile_cache
+
+    monkeypatch.delenv(compile_cache.ENV, raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.cache_dir() == os.path.join(repo, ".jax_cache")
+    monkeypatch.setenv(compile_cache.ENV, "/elsewhere/cache")
+    assert compile_cache.cache_dir() == "/elsewhere/cache"
+    # with the variable set JAX already reads it: the helper sets nothing
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+@pytest.mark.parametrize("child", [
+    "import repro.remote.server",  # benchmarks/bench_coldstart.py's origin
+    # benchmarks/bench_mesh.py's worker
+    "from repro.data import DataLoader, RaDataset; from repro.distributed.data_mesh import DataMesh",
+])
+def test_spawned_children_do_not_import_jax(child):
+    """A chip belongs to one process: the processes benchmarks spawn beside
+    a JAX parent must never load JAX themselves."""
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+    code = f"import sys; {child}; sys.exit(1 if 'jax' in sys.modules else 0)"
+    env = dict(os.environ, PYTHONPATH=src)
+    assert subprocess.run([sys.executable, "-c", code], env=env, timeout=60).returncode == 0
