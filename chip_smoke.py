@@ -183,7 +183,7 @@ def phase_serve(model, params, workdir: str, seed: int) -> None:
     t0 = time.perf_counter()
     out = engine.generate(prompts, NEW_TOKENS)
     print(f"[serve] generate {PROMPTS}x{NEW_TOKENS} tokens in {time.perf_counter() - t0} s "
-          f"(compile included); stats={engine.throughput()}", flush=True)
+          "(compile included)", flush=True)
     check(out.shape == (PROMPTS, NEW_TOKENS), f"generate returned {out.shape}")
     check(bool(((out >= 0) & (out < model.cfg.vocab)).all()), "token outside the vocabulary")
     # reference: the same engine on the host-dequantized weights. With

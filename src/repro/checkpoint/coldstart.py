@@ -44,6 +44,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from .. import core as ra
+from ..core import obs
 from ..core.spec import env_int
 from .store import _entry_quant, _join, _leaf_name, _load_manifest
 
@@ -63,9 +64,9 @@ class ColdStartStats:
     leaves: int = 0
     logical_bytes: int = 0         # sum of restored (post-dequant) leaf bytes
     stored_bytes: int = 0          # sum of on-disk/wire payload bytes
-    resolve_s: float = 0.0         # wave 1: version pins + socket pre-warm
-    restore_s: float = 0.0         # total time to all-weights-resident
-    h2d_s: float = 0.0             # time inside device_put + dequant dispatch
+    resolve_s: float = 0.0         # wave 1: version pins + socket pre-warm (span restore.pin)
+    restore_s: float = 0.0         # total time to all-weights-resident (span restore)
+    h2d_s: float = 0.0             # spans restore.h2d (device_put + dequant) + restore.drain
     h2d_bytes: int = 0             # bytes crossing the host->device boundary
     dequant_leaves: int = 0        # leaves decoded from u8 (device or host)
     prewarmed_conns: int = 0       # sockets opened by pool pre-warm
@@ -91,6 +92,8 @@ class _LeafPlan:
     cost: int = 0                  # budget charge while in flight
     sharding: Any = None           # per-leaf device_put target (or None)
     out: Any = None                # the restored jax.Array
+    span_id: int = 0               # the leaf's restore.leaf span (obs)
+    t_start: int = 0               # its start, obs.now()
 
 
 def shardings_from_specs(mesh, tree: Any) -> Any:
@@ -312,6 +315,7 @@ def _start_warmup(
     if not shapes:
         return None
     errors: List[BaseException] = []
+    parent = obs.current()
 
     def run() -> None:
         try:
@@ -320,19 +324,20 @@ def _start_warmup(
 
             from ..kernels import ops
 
-            for shape, dt in shapes:
-                c = int(shape[-1])
-                # AOT lower+compile only, through the same jit entry (and so
-                # the same tile sizing) as the upload path: executing a
-                # full-size dummy would burn a leaf's worth of device time
-                # and park this thread in block_until_ready, GIL-convoying
-                # against the fetch wave
-                ops.dequant_u8.lower(
-                    jax.ShapeDtypeStruct(shape, jnp.uint8),
-                    jax.ShapeDtypeStruct((c,), jnp.float32),
-                    jax.ShapeDtypeStruct((c,), jnp.float32),
-                    out_dtype=jnp.dtype(dt), interpret=interpret,
-                ).compile()
+            with obs.under(parent), obs.span("restore.warmup", len(shapes)):
+                for shape, dt in shapes:
+                    c = int(shape[-1])
+                    # AOT lower+compile only, through the same jit entry (and
+                    # so the same tile sizing) as the upload path: executing
+                    # a full-size dummy would burn a leaf's worth of device
+                    # time and park this thread in block_until_ready,
+                    # GIL-convoying against the fetch wave
+                    ops.dequant_u8.lower(
+                        jax.ShapeDtypeStruct(shape, jnp.uint8),
+                        jax.ShapeDtypeStruct((c,), jnp.float32),
+                        jax.ShapeDtypeStruct((c,), jnp.float32),
+                        out_dtype=jnp.dtype(dt), interpret=interpret,
+                    ).compile()
         except BaseException as e:  # noqa: BLE001 — re-raised by restore_pipelined
             errors.append(e)
 
@@ -377,11 +382,36 @@ def restore_pipelined(
     mtime+size, remote ETag) changes mid-restore, and propagates auth/
     transport errors unchanged (fail fast — never a silently mixed
     checkpoint)."""
+    st = stats if stats is not None else ColdStartStats()
+    st.inflight_cap = max(1, inflight_bytes if inflight_bytes is not None else default_inflight_bytes())
+    with obs.span("restore") as sp:
+        out = _restore_pipelined(path, params_like, opt_like, st, device=device,
+                                 shardings=shardings, opt_shardings=opt_shardings,
+                                 interpret=interpret, prewarm=prewarm,
+                                 _after_resolve=_after_resolve)
+    st.restore_s = sp.seconds
+    return out
+
+
+def _restore_pipelined(
+    path: str,
+    params_like: Any,
+    opt_like: Any,
+    st: ColdStartStats,
+    *,
+    device: Any,
+    shardings: Any,
+    opt_shardings: Any,
+    interpret: Optional[bool],
+    prewarm: bool,
+    _after_resolve: Optional[Callable[[], None]],
+) -> Tuple[Any, Any, Dict[str, Any]]:
+    """The body of :func:`restore_pipelined`, run inside its ``restore``
+    span, which every span of the restore names as an ancestor."""
     import jax
 
-    st = stats if stats is not None else ColdStartStats()
-    st.inflight_cap = cap = max(1, inflight_bytes if inflight_bytes is not None else default_inflight_bytes())
-    t_all = time.perf_counter()
+    restore_id = obs.current()
+    cap = st.inflight_cap
     manifest = _load_manifest(path)
 
     # ---- plan construction (tree order preserved for reassembly) ----------
@@ -432,7 +462,7 @@ def restore_pipelined(
     st.leaves = len(plans)
 
     # ---- wave 1: pin versions + prewarm sockets (overlapped) --------------
-    t0 = time.perf_counter()
+    t_pins = obs.now()
     warmup: Optional[Tuple[threading.Thread, List[BaseException]]] = None
     # a thread that finishes a leaf wakes the scheduler / dispatches H2D
     # through the GIL, and CPython's default 5ms switch interval is the
@@ -492,7 +522,8 @@ def restore_pipelined(
                 with stats_lock:
                     pins_left[0] -= 1
                     if pins_left[0] == 0:
-                        st.resolve_s = time.perf_counter() - t0
+                        st.resolve_s = obs.record("restore.pin", t_pins, obs.now(),
+                                                  parent=restore_id, n=len(order)).seconds
                         pins_done.set()
 
         inline = (
@@ -531,38 +562,38 @@ def restore_pipelined(
             runs after the wave drains."""
             try:
                 _check_local_pin(plan)
-                t0 = time.perf_counter()
-                if plan.quant is not None and plan.sharding is not None:
-                    # multi-target leaf: host dequant, then shard-put
-                    arr = plan.quant.dequantize(arr)
-                    out = jax.device_put(arr, plan.sharding)
-                    dequant = True
-                elif plan.quant is not None and plan.hdr.shape:
-                    # u8 over the link, fused dequant on device
-                    from ..kernels import ops  # deferred: pallas is heavy
-
-                    moved = jax.device_put(arr, device)
-                    c = int(plan.hdr.shape[-1])
-                    scale, bias = plan.quant.channel_params(c)
-                    if device is not None:
-                        # jit places uncommitted args on the DEFAULT device;
-                        # an explicit target needs explicit puts
-                        scale = jax.device_put(scale, device)
-                        bias = jax.device_put(bias, device)
-                    out = ops.dequant_u8(
-                        moved, scale, bias,
-                        out_dtype=np.dtype(plan.quant.orig_dtype), interpret=interpret,
-                    )
-                    dequant = True
-                else:
-                    dequant = plan.quant is not None
-                    if dequant:  # 0-d quantized: host decode
+                with obs.under(plan.span_id), obs.span("restore.h2d") as sp:
+                    if plan.quant is not None and plan.sharding is not None:
+                        # multi-target leaf: host dequant, then shard-put
                         arr = plan.quant.dequantize(arr)
-                    out = jax.device_put(arr, plan.sharding if plan.sharding is not None else device)
-                dt = time.perf_counter() - t0
+                        out = jax.device_put(arr, plan.sharding)
+                        dequant = True
+                    elif plan.quant is not None and plan.hdr.shape:
+                        # u8 over the link, fused dequant on device
+                        from ..kernels import ops  # deferred: pallas is heavy
+
+                        moved = jax.device_put(arr, device)
+                        c = int(plan.hdr.shape[-1])
+                        scale, bias = plan.quant.channel_params(c)
+                        if device is not None:
+                            # jit places uncommitted args on the DEFAULT
+                            # device; an explicit target needs explicit puts
+                            scale = jax.device_put(scale, device)
+                            bias = jax.device_put(bias, device)
+                        out = ops.dequant_u8(
+                            moved, scale, bias,
+                            out_dtype=np.dtype(plan.quant.orig_dtype), interpret=interpret,
+                        )
+                        dequant = True
+                    else:
+                        dequant = plan.quant is not None
+                        if dequant:  # 0-d quantized: host decode
+                            arr = plan.quant.dequantize(arr)
+                        out = jax.device_put(arr, plan.sharding if plan.sharding is not None else device)
+                    sp.n = int(arr.nbytes)
                 plan.out = out
                 with stats_lock:
-                    st.h2d_s += dt
+                    st.h2d_s += sp.seconds
                     st.h2d_bytes += int(arr.nbytes)
                     if dequant:
                         st.dequant_leaves += 1
@@ -573,12 +604,17 @@ def restore_pipelined(
                 # buffer may outlive the release by the (short) tail of an
                 # async copy jax is still draining
                 budget.release(plan.cost)
+                obs.record("restore.leaf", plan.t_start, obs.now(), parent=restore_id,
+                           n=int(plan.hdr.data_length), id=plan.span_id)
                 _count_done()
 
         def _drive_leaf(plan: _LeafPlan) -> None:
             """Resolve header/table/quant, then fan out the payload tasks —
             runs on the pool, so many leaves resolve concurrently and their
             round trips hide under other leaves' fetch/decode."""
+            # the leaf's span runs from here to the end of _finish_leaf, on
+            # whichever thread finishes it; its payload tasks run under it
+            plan.span_id, plan.t_start = obs.new_id(), obs.now()
             try:
                 # FIFO guarantees this leaf's pin task was dequeued before
                 # this driver, so the wait is at most one in-flight HEAD
@@ -604,7 +640,8 @@ def restore_pipelined(
             def _wrap(t: Callable[[], None]) -> None:
                 try:
                     if not first_err:
-                        t()
+                        with obs.under(plan.span_id):
+                            t()
                 except BaseException as e:  # noqa: BLE001 — forwarded
                     _fail(e)
                 finally:
@@ -625,7 +662,9 @@ def restore_pipelined(
                     pool.submit(_wrap, t)
 
         for plan in order:
-            if not budget.admit(plan.cost):
+            with obs.span("restore.admit_wait", plan.cost):
+                admitted = budget.admit(plan.cost)
+            if not admitted:
                 _count_done()  # never scheduled; keep the ledger whole
                 continue
             if first_err:
@@ -652,9 +691,9 @@ def restore_pipelined(
         # one quiet barrier for every async transfer/dequant the completion
         # threads enqueued — the pool is drained, so this wait runs without
         # GIL competition and finishes at memcpy speed
-        t0 = time.perf_counter()
-        jax.block_until_ready([p.out for p in plans])
-        st.h2d_s += time.perf_counter() - t0
+        with obs.span("restore.drain") as sp:
+            jax.block_until_ready([p.out for p in plans])
+        st.h2d_s += sp.seconds
     finally:
         sys.setswitchinterval(prev_switch)
         if warmup is not None:
@@ -669,7 +708,6 @@ def restore_pipelined(
     if warmup is not None and warmup[1]:
         raise warmup[1][0]
     st.peak_inflight_bytes = budget.peak
-    st.restore_s = time.perf_counter() - t_all
 
     # ---- reassemble trees in original leaf order --------------------------
     outs: List[Any] = []

@@ -35,22 +35,23 @@ file written elsewhere with an unavailable codec fails with a clear error
 instead of an ImportError. ``RA_CODEC`` picks the default codec name and
 ``RA_CHUNK_BYTES`` the default chunk size (1 MiB).
 
-Module-level counters (``stats()`` / ``reset_stats()``) count every chunk
-actually fetched + decompressed — the observable that proves partial reads
-touch only overlapping chunks (surfaced via ``RaDataset.io_stats()``).
+Process-wide counters (``stats()`` / ``reset_stats()``, kept in
+``repro.core.obs``) count every chunk actually fetched + decompressed — the
+observable that proves partial reads touch only overlapping chunks (surfaced
+via ``RaDataset.io_stats()``). Each chunk's fetch and inflate are spans
+(``codec.fetch``, ``codec.inflate``).
 """
 
 from __future__ import annotations
 
 import os
-import threading
 import zlib
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Tuple, Union
 
 import numpy as np
 
-from . import engine, layouts
+from . import engine, layouts, obs
 from .spec import RawArrayError, env_int as _env_int, env_str as _env_str
 
 CHUNK_MAGIC: int = layouts.CHUNK_TABLE.magic_int
@@ -145,29 +146,20 @@ except ImportError:
 
 
 # ------------------------------------------------------------- read counters
-_stats_lock = threading.Lock()
-# Audit note (ralint guarded-by): every _count/stats/reset_stats access was
-# already under _stats_lock when audited; the annotation locks that in.
-_stats = {"chunk_reads": 0, "chunk_stored_bytes": 0, "chunk_raw_bytes": 0}  # guarded-by: _stats_lock
-
-
-def _count(stored: int, raw: int) -> None:
-    with _stats_lock:
-        _stats["chunk_reads"] += 1
-        _stats["chunk_stored_bytes"] += stored
-        _stats["chunk_raw_bytes"] += raw
+_COUNTERS = {k: obs.counter("codec." + k)
+             for k in ("chunk_reads", "chunk_stored_bytes", "chunk_raw_bytes")}
+_chunk_reads, _chunk_stored_bytes, _chunk_raw_bytes = _COUNTERS.values()
 
 
 def stats() -> Dict[str, int]:
-    """Process-wide chunk decode counters (chunks fetched+decompressed)."""
-    with _stats_lock:
-        return dict(_stats)
+    """Process-wide chunk decode counters (chunks fetched+decompressed); a
+    view of the ``codec.*`` counters of ``repro.core.obs``."""
+    return {k: c.value for k, c in _COUNTERS.items()}
 
 
 def reset_stats() -> None:
-    with _stats_lock:
-        for k in _stats:
-            _stats[k] = 0
+    for c in _COUNTERS.values():
+        c.reset()
 
 
 # --------------------------------------------------------------- chunk table
@@ -524,19 +516,23 @@ def _decode_chunk(src, hdr, table: ChunkTable, c: Codec, i: int):
     scratch = engine.acquire_scratch(slen)
     try:
         stored = memoryview(scratch)[:slen]
-        engine.pread_into(src, hdr.nbytes + so, stored)
-        if zlib.crc32(stored) != int(table.crcs[i]):
-            raise RawArrayError(f"chunk {i} CRC32 mismatch: stored bytes corrupted")
-        raw = c.decompress(stored)
-        if raw is stored:  # store codec: detach from scratch before recycling
-            raw = bytes(stored)
+        with obs.span("codec.fetch", slen):
+            engine.pread_into(src, hdr.nbytes + so, stored)
+        with obs.span("codec.inflate", rlen):
+            if zlib.crc32(stored) != int(table.crcs[i]):
+                raise RawArrayError(f"chunk {i} CRC32 mismatch: stored bytes corrupted")
+            raw = c.decompress(stored)
+            if raw is stored:  # store codec: detach from scratch before recycling
+                raw = bytes(stored)
+            if len(raw) != rlen:
+                raise RawArrayError(
+                    f"chunk {i} decompressed to {len(raw)} bytes, table wants {rlen}"
+                )
     finally:
         engine.release_scratch(scratch)
-    if len(raw) != rlen:
-        raise RawArrayError(
-            f"chunk {i} decompressed to {len(raw)} bytes, table wants {rlen}"
-        )
-    _count(slen, rlen)
+    _chunk_reads.add(1)
+    _chunk_stored_bytes.add(slen)
+    _chunk_raw_bytes.add(rlen)
     return raw
 
 

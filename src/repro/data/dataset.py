@@ -40,7 +40,7 @@ import numpy as np
 
 from .. import core as ra
 from ..core import codec as chunked_codec
-from ..core import engine
+from ..core import engine, obs
 from ..core.dtypes import is_float
 
 MANIFEST = "manifest.json"
@@ -497,6 +497,12 @@ class RaDataset:
     ) -> Dict[str, np.ndarray]:
         """Read global rows [start, stop) across shard boundaries — one
         engine wave of positioned reads into a single buffer per field."""
+        with obs.span("dataset.rows") as sp:
+            result = self._rows(start, stop, fields, out)
+            sp.n = sum(a.nbytes for a in result.values())
+        return result
+
+    def _rows(self, start, stop, fields, out) -> Dict[str, np.ndarray]:
         fields = list(fields or self.fields)
         start, stop = max(0, start), min(stop, self.total_rows)
         n = max(0, stop - start)
@@ -550,6 +556,12 @@ class RaDataset:
         requests too sparse to coalesce fall back to fancy indexing on the
         cached mmap — the planner never reads more than ``gap+1`` times the
         requested bytes."""
+        with obs.span("dataset.gather") as sp:
+            result = self._gather(indices, fields, out)
+            sp.n = sum(a.nbytes for a in result.values())
+        return result
+
+    def _gather(self, indices, fields, out) -> Dict[str, np.ndarray]:
         fields = list(fields or self.fields)
         indices = np.asarray(indices, dtype=np.int64)
         n = len(indices)

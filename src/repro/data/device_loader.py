@@ -46,11 +46,11 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Any, Dict, Iterator, Optional, Tuple
 
 import numpy as np
 
+from ..core import obs
 from ..core.spec import RawArrayError, env_int
 from .loader import DataLoader, LoaderState
 
@@ -169,38 +169,40 @@ class DeviceLoader:
                 try:
                     batch = next(loader)
                     state = batch.pop("_state", None)
-                    t0 = time.perf_counter()
-                    if self.global_arrays:
-                        moved = self._globalize(batch, detach)
-                    else:
-                        moved = {
-                            k: jax.device_put(
-                                np.array(v, copy=True) if detach else v, dev
-                            )
-                            for k, v in batch.items()
-                        }
-                        # the transfer must COMPLETE before the next host
-                        # batch may recycle the staging ring buffer under it
-                        jax.block_until_ready(list(moved.values()))
-                    nbytes = sum(int(v.nbytes) for v in batch.values())
+                    with obs.span("device_loader.h2d") as sp:
+                        if self.global_arrays:
+                            moved = self._globalize(batch, detach)
+                        else:
+                            moved = {
+                                k: jax.device_put(
+                                    np.array(v, copy=True) if detach else v, dev
+                                )
+                                for k, v in batch.items()
+                            }
+                            # the transfer must COMPLETE before the next host
+                            # batch may recycle the staging ring buffer under it
+                            jax.block_until_ready(list(moved.values()))
+                        sp.n = nbytes = sum(int(v.nbytes) for v in batch.values())
                     with self._stats_lock:
-                        self._h2d_s += time.perf_counter() - t0
+                        self._h2d_s += sp.seconds
                         self._h2d_bytes += nbytes
                         self._h2d_n += 1
                     if not self.global_arrays:
                         # on-device decode is part of the FEED pipeline:
                         # dispatch the fused dequant here so the consumer's
                         # critical path is nothing but q.get() + train step
-                        self._dequant_on_device(moved)
+                        with obs.span("device_loader.dequant"):
+                            self._dequant_on_device(moved)
                     item: Any = (moved, state)
                 except Exception as e:  # surface in consumer (sticky there)
                     item = e
-                while not stop.is_set():
-                    try:
-                        q.put(item, timeout=0.2)
-                        break
-                    except queue.Full:
-                        continue
+                with obs.span("device_loader.put_wait"):
+                    while not stop.is_set():
+                        try:
+                            q.put(item, timeout=0.2)
+                            break
+                        except queue.Full:
+                            continue
                 if isinstance(item, Exception):
                     return
 
@@ -307,10 +309,10 @@ class DeviceLoader:
             raise self._exc  # sticky, same contract as DataLoader.__next__
         if self._q is None:
             self._start()
-        t0 = time.perf_counter()
-        item = self._q.get()
+        with obs.span("device_loader.wait") as sp:
+            item = self._q.get()
         with self._stats_lock:
-            self._wait_s += time.perf_counter() - t0
+            self._wait_s += sp.seconds
         if isinstance(item, Exception):
             self._exc = item
             raise item

@@ -49,12 +49,12 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from dataclasses import dataclass
 from typing import Any, Dict, Iterator, Optional
 
 import numpy as np
 
+from ..core import obs
 from ..core.spec import RawArrayError
 from .dataset import RaDataset
 
@@ -321,10 +321,10 @@ class DataLoader:
             raise self._exc
         if self._q is None:
             self._start_prefetch()
-        t0 = time.perf_counter()
-        batch = self._q.get()
+        with obs.span("loader.wait") as sp:
+            batch = self._q.get()
         with self._stats_lock:
-            self._wait_s += time.perf_counter() - t0
+            self._wait_s += sp.seconds
             self._n_batches += 1
         if isinstance(batch, Exception):
             self._exc = batch
@@ -375,14 +375,14 @@ class DataLoader:
                     spe = self._spe(epoch)
                     continue
                 try:
-                    t0 = time.perf_counter()
-                    buf = None
-                    if ring is not None:
-                        buf = ring[pos % len(ring)]
-                        pos += 1
-                    b = self._produce(epoch, step, buf)
+                    with obs.span("loader.produce", self.batch_size) as sp:
+                        buf = None
+                        if ring is not None:
+                            buf = ring[pos % len(ring)]
+                            pos += 1
+                        b = self._produce(epoch, step, buf)
                     with self._stats_lock:
-                        self._produce_s += time.perf_counter() - t0
+                        self._produce_s += sp.seconds
                 except Exception as e:  # surface in consumer (sticky there)
                     while not stop.is_set():
                         try:

@@ -9,8 +9,7 @@ batch for simplicity), then decoded step by step with a shared KV cache.
 
 from __future__ import annotations
 
-import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +46,6 @@ class ServeEngine:
         self.params = params
         self._prefill = jax.jit(model.prefill)
         self._decode = jax.jit(model.decode_step, donate_argnums=(1,))
-        self.stats: Dict[str, float] = {"prefill_s": 0.0, "decode_s": 0.0, "tokens": 0.0}
 
     def generate(
         self,
@@ -58,14 +56,10 @@ class ServeEngine:
         seed: int = 0,
     ) -> np.ndarray:
         B, S = prompts.shape
-        t0 = time.perf_counter()
         logits, cache = self._prefill_with_capacity(prompts, S + max_new)
-        jax.block_until_ready(logits)
-        self.stats["prefill_s"] += time.perf_counter() - t0
 
         out = np.zeros((B, max_new), dtype=np.int32)
         rng = jax.random.PRNGKey(seed)
-        t0 = time.perf_counter()
         tok = self._sample(logits, temperature, rng)
         out[:, 0] = np.asarray(tok)[:, 0]
         for i in range(1, max_new):
@@ -73,9 +67,6 @@ class ServeEngine:
             logits, cache = self._decode(self.params, cache, tok)
             tok = self._sample(logits, temperature, sub)
             out[:, i] = np.asarray(tok)[:, 0]
-        jax.block_until_ready(tok)
-        self.stats["decode_s"] += time.perf_counter() - t0
-        self.stats["tokens"] += B * max_new
         return out
 
     def _prefill_with_capacity(self, prompts: np.ndarray, capacity: int):
@@ -114,9 +105,3 @@ class ServeEngine:
         if temperature <= 0:
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
         return jax.random.categorical(rng, logits / temperature, axis=-1).astype(jnp.int32)[:, None]
-
-    def throughput(self) -> Dict[str, float]:
-        d = dict(self.stats)
-        if d["decode_s"] > 0:
-            d["decode_tok_per_s"] = d["tokens"] / d["decode_s"]
-        return d

@@ -32,6 +32,7 @@ MODULES = [
     ("repro.core.codec", "chunked compression codec"),
     ("repro.core.stats", "per-chunk statistics + predicate pushdown"),
     ("repro.core.layouts", "single-source-of-truth on-disk layout registry"),
+    ("repro.core.obs", "in-program spans and counters on the device trace's clock"),
     ("repro.core.sharded", "sharded stores (read + streaming write)"),
     ("repro.core.racat", "CLI introspection / verify / compress / ingest"),
     ("repro.remote.server", "HTTP byte-range + upload server"),
