@@ -21,8 +21,8 @@ what makes multi-host sharded reads and exact-resume trivial.
 becomes a pooled byte-range request through ``repro.remote``, and the
 block cache turns repeated epoch traversals into RAM hits. The engine's
 ``rows``/``gather`` wave plans are identical in both modes; only the
-sparse-leftover path differs (ranged reads instead of mmap fancy
-indexing, since there is nothing to map).
+sparse-leftover path differs (ranged reads through the block cache instead
+of row copies out of the shard mmaps, since there is nothing to map).
 
 Datasets are built by streaming (DESIGN.md §11): ``DatasetBuilder`` feeds
 samples or row batches through per-field incremental writers in bounded
@@ -34,7 +34,7 @@ from __future__ import annotations
 import json
 import os
 from dataclasses import dataclass
-from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -46,6 +46,22 @@ from ..core.dtypes import is_float
 MANIFEST = "manifest.json"
 
 _join = ra.join_path
+
+# A local plain row at least this wide that no coalesced run covers is
+# copied from the shard's mmap straight into its batch slot, on the engine
+# pool; a narrower one is cheaper to take in one vectorized fancy index,
+# which copies twice (into a temporary, then into the batch) (DESIGN.md §8).
+_DIRECT_ROW_BYTES = 64 << 10
+# Pool tasks those copies are split into. Copying into a fresh batch is
+# bound by faulting its pages in, which scales little with threads, while
+# every thread slows the feeder's host-to-device copy running beside it: on
+# a TPU v5e host, 256 ImageNet rows a batch took 23.8 ms in 8 tasks and
+# 28.8 ms in 2, with that copy at 42.9 and 41.5 ms, and 2 fed the chip more.
+_ROW_COPY_TASKS = 2
+
+# (row, field) pairs a gather served outside coalesced runs, by either path
+_DIRECT_ROWS = obs.counter("dataset.direct_rows")
+_FANCY_ROWS = obs.counter("dataset.fancy_rows")
 
 
 def dataset_manifest(root: str) -> Dict[str, Any]:
@@ -267,14 +283,35 @@ class _Shard:
     row_offset: int
 
 
+def _row_copy_tasks(
+    jobs: List[Tuple[np.ndarray, int, np.ndarray, int]]
+) -> List[Callable[[], None]]:
+    """``(batch, slot, shard mmap, row)`` copies, ``batch[slot] = mmap[row]``,
+    as ``_ROW_COPY_TASKS`` pool tasks, each a contiguous slice of ``jobs``
+    (a wide row's copy runs without the GIL; a task per row would cost more
+    in futures than the copy)."""
+    if not jobs:
+        return []
+    k = min(_ROW_COPY_TASKS, len(jobs))
+    cuts = [len(jobs) * i // k for i in range(k + 1)]
+
+    def task(part):
+        for dst, slot, src, row in part:
+            dst[slot] = src[row]
+
+    return [(lambda part=jobs[a:b]: task(part)) for a, b in zip(cuts, cuts[1:])]
+
+
 class RaDataset:
     """Random-access reader over a shard directory.
 
     Contiguous reads (``rows``) go through the parallel I/O engine in one
     wave of positioned preads straight into the output batch buffer; random
     gathers (``gather``) are planned by ``engine.coalesce`` — dense index
-    runs become ranged reads, sparse leftovers fall back to fancy indexing
-    on the cached per-shard mmaps (DESIGN.md §8). Both accept ``out=`` so a
+    runs become ranged reads; sparse leftovers are copied out of the cached
+    per-shard mmaps, one row at a time straight into the batch on the pool
+    when the row is wide, by one fancy index when it is narrow
+    (DESIGN.md §8). Both accept ``out=`` so a
     loader can stream into reused, pre-faulted batch arrays.
     """
 
@@ -553,9 +590,11 @@ class RaDataset:
         Per shard, ``engine.coalesce`` merges near-adjacent requests into
         ranged positioned reads (served from reusable scratch buffers, or
         read directly into the output when the destination rows line up);
-        requests too sparse to coalesce fall back to fancy indexing on the
-        cached mmap — the planner never reads more than ``gap+1`` times the
-        requested bytes."""
+        requests too sparse to coalesce are copied out of the cached mmap,
+        one row at a time into their slots on the pool when the row is wide
+        (``_DIRECT_ROW_BYTES``), by one fancy index when it is narrow — the
+        planner never reads more than ``gap+1`` times the requested
+        bytes."""
         with obs.span("dataset.gather") as sp:
             result = self._gather(indices, fields, out)
             sp.n = sum(a.nbytes for a in result.values())
@@ -597,7 +636,8 @@ class RaDataset:
                                                     min_run=min_run)
             plans.append((si, local, order[a:b], plain_plan))
         tasks = []
-        fancy = []  # deferred sparse leftovers: (si, field, positions, local)
+        fancy = []  # deferred narrow sparse leftovers: (si, field, positions, local)
+        direct = []  # wide sparse leftovers: (batch, slot, shard mmap, row)
         for f in fields:
             rshape, dtype = self._field_spec(f)
             sample = result[f]
@@ -619,12 +659,19 @@ class RaDataset:
                             self._run_task(run, sidx, order, sample, rshape, dtype,
                                            read_raw, rnb, self.shards[si].row_offset)
                         )
-                if leftover.size:
-                    fancy.append((si, f, order[leftover], sidx[leftover]
-                                  - self.shards[si].row_offset))
-        engine.run_tasks(tasks)
+                if not leftover.size:
+                    continue
+                pos, loc = order[leftover], sidx[leftover] - self.shards[si].row_offset
+                if rnb < _DIRECT_ROW_BYTES:
+                    fancy.append((si, f, pos, loc))
+                    continue
+                mm = self._mmap(si, f)
+                direct += [(sample, p, mm, lc) for p, lc in zip(pos.tolist(), loc.tolist())]
+        engine.run_tasks(tasks + _row_copy_tasks(direct))
+        _DIRECT_ROWS.add(len(direct))
         for si, f, pos, loc in fancy:
             result[f][pos] = self._mmap(si, f)[loc]
+            _FANCY_ROWS.add(len(pos))
         return result
 
     @staticmethod

@@ -179,8 +179,40 @@ def dataset(tmp_path_factory):
     return RaDataset(root)
 
 
-@pytest.mark.parametrize("trial", range(10))
-def test_gather_matches_naive_on_random_patterns(dataset, trial):
+def _wide_dataset(root, **kw):
+    """Two plain fields whose rows are wider than the direct-copy threshold
+    (u8 and float32) beside a 4-byte label, in four shards of 40 rows."""
+    from repro.data import DatasetBuilder
+    from repro.data.dataset import _DIRECT_ROW_BYTES
+
+    img, emb = (80, 1024), (16640,)
+    assert 80 * 1024 >= _DIRECT_ROW_BYTES and 4 * 16640 >= _DIRECT_ROW_BYTES
+    rng = np.random.default_rng(5)
+    b = DatasetBuilder(root, {"image": (img, "uint8"), "emb": (emb, "float32"),
+                              "label": ((), "int32")}, shard_rows=40, **kw)
+    b.append(image=rng.integers(0, 256, size=(160,) + img, dtype=np.uint8),
+             emb=rng.normal(size=(160,) + emb).astype(np.float32),
+             label=np.arange(160, dtype=np.int32))
+    b.finish()
+    return root
+
+
+@pytest.fixture(scope="module")
+def wide_dataset(tmp_path_factory):
+    from repro.data import RaDataset
+
+    return RaDataset(_wide_dataset(str(tmp_path_factory.mktemp("eng") / "wide")))
+
+
+# the narrow token set (rows of 96 B and 4 B) keeps its seed ids; the wide
+# set's rows are copied straight into the batch outside coalesced runs
+GATHER_CASES = ([pytest.param("dataset", t, id=str(t)) for t in range(10)]
+                + [pytest.param("wide_dataset", t, id=f"wide-{t}") for t in range(9)])
+
+
+@pytest.mark.parametrize("which,trial", GATHER_CASES)
+def test_gather_matches_naive_on_random_patterns(request, which, trial):
+    dataset = request.getfixturevalue(which)
     rng = np.random.default_rng(100 + trial)
     n = int(rng.integers(1, 120))
     if trial % 3 == 0:       # dense-ish: exercises the coalesced ranged reads
@@ -190,11 +222,60 @@ def test_gather_matches_naive_on_random_patterns(dataset, trial):
         idx = rng.integers(0, len(dataset), size=n)
     else:                    # sorted contiguous with dups: exercises direct reads
         idx = np.sort(rng.integers(0, len(dataset), size=n))
-    got = dataset.gather(idx)
-    want = dataset.gather_naive(idx)
-    assert set(got) == set(want)
-    for f in got:
+    if which == "dataset":
+        got = dataset.gather(idx)
+        want = dataset.gather_naive(idx)
+        assert set(got) == set(want)
+        for f in got:
+            assert np.array_equal(got[f], want[f]), f
+        return
+    # wide: shuffled across shards with duplicates, gathered in turn into
+    # the slots of a preallocated ring as the loader's reused buffers are
+    idx = idx % len(dataset)
+    idx = rng.permutation(np.concatenate([idx, rng.choice(idx, size=len(idx) // 3 + 1)]))
+    ring = [{f: np.full((len(idx),) + tuple(i["shape"]), 7, i["dtype"])
+             for f, i in dataset.fields.items()} for _ in range(2)]
+    for slot, batch in enumerate((idx, idx[::-1].copy())):
+        got = dataset.gather(batch, out=ring[slot] if trial % 2 else None)
+        want = dataset.gather_naive(batch)
+        assert set(got) == set(want)
+        for f in got:
+            if trial % 2:
+                assert got[f] is ring[slot][f]
+            assert np.array_equal(got[f], want[f]), f
+
+
+def _counted(ds, idx, fields=None):
+    """``gather`` with the deltas of the two leftover counters."""
+    from repro.core import obs
+
+    d, f = obs.counter("dataset.direct_rows"), obs.counter("dataset.fancy_rows")
+    d0, f0 = d.value, f.value
+    got = ds.gather(idx, fields)
+    return got, d.value - d0, f.value - f0
+
+
+# every third row, shuffled and crossing all four shards: no coalesced run
+SPARSE = np.random.default_rng(3).permutation(np.arange(0, 160, 3))
+
+
+@pytest.mark.parametrize("field,direct", [("image", True), ("emb", True), ("label", False)])
+def test_gather_counts_direct_rows_for_wide_plain_rows_only(wide_dataset, field, direct):
+    got, nd, nf = _counted(wide_dataset, SPARSE, [field])
+    assert (nd, nf) == ((len(SPARSE), 0) if direct else (0, len(SPARSE)))
+    assert np.array_equal(got[field], wide_dataset.gather_naive(SPARSE, [field])[field])
+
+
+def test_gather_reads_no_direct_rows_from_chunked_wide_fields(tmp_path, wide_dataset):
+    from repro.data import RaDataset
+
+    ds = RaDataset(_wide_dataset(str(tmp_path / "chunked"), chunked=True, codec="zlib"))
+    got, nd, nf = _counted(ds, SPARSE)
+    assert nd == 0 and nf == 0
+    want = wide_dataset.gather_naive(SPARSE)
+    for f in want:
         assert np.array_equal(got[f], want[f]), f
+    ds.close()
 
 
 def test_gather_into_preallocated_out(dataset):

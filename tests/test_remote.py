@@ -347,6 +347,31 @@ def test_dataset_rows_and_gather_remote(served):
     local.close()
 
 
+def test_remote_gather_of_wide_rows_reads_no_direct_rows(served):
+    """A remote gather plans every request as a ranged read through the
+    block cache (``min_run=1``): wide rows leave nothing to the local
+    direct-copy path or to the mmap fallback, and read the same bytes."""
+    from repro.core import obs
+    from repro.data import DatasetBuilder
+    from repro.data.dataset import _DIRECT_ROW_BYTES
+
+    root, base = served
+    width = _DIRECT_ROW_BYTES + 4096
+    b = DatasetBuilder(os.path.join(root, "wide"), {"image": ((width,), "uint8")}, shard_rows=16)
+    b.append(image=np.random.default_rng(2).integers(0, 256, size=(64, width), dtype=np.uint8))
+    b.finish()
+    local = RaDataset(os.path.join(root, "wide"))
+    rem = RaDataset(f"{base}/wide")
+    idx = np.random.default_rng(4).permutation(np.arange(0, 64, 3))
+    d, f = obs.counter("dataset.direct_rows"), obs.counter("dataset.fancy_rows")
+    d0, f0 = d.value, f.value
+    got = rem.gather(idx)
+    assert (d.value - d0, f.value - f0) == (0, 0)
+    assert np.array_equal(got["image"], local.gather_naive(idx)["image"])
+    rem.close()
+    local.close()
+
+
 def test_loader_streams_remote_batches(served):
     root, base = served
     _make_dataset(root, rows=256)
