@@ -54,6 +54,9 @@ from ..core import obs
 from ..core.spec import RawArrayError, env_int
 from .loader import DataLoader, LoaderState
 
+# device blocks the global-assembly path put, one per local device a batch
+_SHARDS = obs.counter("device_loader.shards")
+
 
 def default_device_bufs() -> int:
     """Device-resident batch depth (knob ``RA_DEVICE_BUFS``, default 2)."""
@@ -100,8 +103,8 @@ class DeviceLoader:
                 raise RawArrayError(
                     f"global assembly needs one jax process per mesh host: "
                     f"mesh has {mesh.host_count} hosts but "
-                    f"jax.process_count()={jax.process_count()} (use "
-                    f"data_mesh.make_global_batch directly to simulate)"
+                    f"jax.process_count()={jax.process_count()} (several "
+                    f"mesh hosts in one process take global_arrays=False)"
                 )
         self._gsharding: Any = None  # lazy data_mesh.data_sharding()
         self._gdevices: Any = None
@@ -111,6 +114,7 @@ class DeviceLoader:
         self.device = device
         self._interpret = interpret
         self._quant_dev: Dict[str, Tuple[Any, Any, np.dtype]] = {}
+        self._quant_dev_on: Dict[Any, Dict[str, Tuple[Any, Any, np.dtype]]] = {}
         # Regression note (ralint guarded-by): the feeder thread writes the
         # h2d_* counters while the consumer writes _wait_s/_n_batches and
         # stats() reads both — previously with no lock anywhere.
@@ -171,7 +175,7 @@ class DeviceLoader:
                     state = batch.pop("_state", None)
                     with obs.span("device_loader.h2d") as sp:
                         if self.global_arrays:
-                            moved = self._globalize(batch, detach)
+                            moved = self._put_shards(batch, detach)
                         else:
                             moved = {
                                 k: jax.device_put(
@@ -187,11 +191,13 @@ class DeviceLoader:
                         self._h2d_s += sp.seconds
                         self._h2d_bytes += nbytes
                         self._h2d_n += 1
-                    if not self.global_arrays:
-                        # on-device decode is part of the FEED pipeline:
-                        # dispatch the fused dequant here so the consumer's
-                        # critical path is nothing but q.get() + train step
-                        with obs.span("device_loader.dequant"):
+                    # on-device decode is part of the FEED pipeline: dispatch
+                    # the fused dequant here so the consumer's critical path
+                    # is nothing but q.get() + train step
+                    with obs.span("device_loader.dequant"):
+                        if self.global_arrays:
+                            moved = self._assemble(moved)
+                        else:
                             self._dequant_on_device(moved)
                     item: Any = (moved, state)
                 except Exception as e:  # surface in consumer (sticky there)
@@ -214,12 +220,9 @@ class DeviceLoader:
         """Per-field dequant parameters COMMITTED to ``device`` — committed
         operands keep the fused kernel's dispatch on each shard's own device
         in the global-assembly path."""
-        cache = getattr(self, "_quant_dev_on", None)
-        if cache is None:
-            cache = self._quant_dev_on = {}
-        per = cache.get(device)
+        per = self._quant_dev_on.get(device)
         if per is None:
-            per = cache[device] = {}
+            per = self._quant_dev_on[device] = {}
             for f, info in getattr(self.loader.ds, "quant", {}).items():
                 shape, _ = self.loader.ds.logical_spec(f)
                 scale, bias = info.channel_params(int(shape[-1]))
@@ -230,13 +233,12 @@ class DeviceLoader:
                 )
         return per
 
-    def _globalize(self, batch: Dict[str, np.ndarray], detach: bool) -> Dict[str, Any]:
-        """Local host batch → global ``jax.Array``s: split rows over this
-        host's addressable devices, device_put each block (uint8 for
-        quantized fields), dequant each block on ITS device, then declare
-        the blocks as the addressable shards of the
-        ``(host_count * local_B, ...)``-shaped global array. The assembly
-        itself is metadata-only — no gather, no cross-host traffic."""
+    def _put_shards(self, batch: Dict[str, np.ndarray], detach: bool) -> list:
+        """Split the local host batch's rows over this host's addressable
+        devices and ``device_put`` each device's block of every field
+        (uint8 for quantized fields): one ``device_loader.shard_put`` span
+        per device around its puts, then one wait for every transfer. Returns
+        one ``{field: single-device array}`` per device, in device order."""
         jax = self._jax
         if self._gsharding is None:
             from ..distributed import data_mesh
@@ -245,8 +247,6 @@ class DeviceLoader:
             self._gdevices = jax.local_devices()
         devs = self._gdevices
         nd = len(devs)
-        host_count = self.loader.mesh.host_count
-        out: Dict[str, Any] = {}
         for k, v in batch.items():
             n = int(v.shape[0])
             if n % nd:
@@ -254,30 +254,45 @@ class DeviceLoader:
                     f"{k}: local batch of {n} rows does not split over "
                     f"{nd} local devices"
                 )
-            per = n // nd
-            shards = [
-                jax.device_put(
-                    np.array(v[i * per : (i + 1) * per], copy=True)
-                    if detach
-                    else v[i * per : (i + 1) * per],
-                    d,
-                )
-                for i, d in enumerate(devs)
-            ]
-            # transfers must COMPLETE before the staging ring may recycle
-            jax.block_until_ready(shards)
-            if k in getattr(self.loader.ds, "quant", {}):
+        blocks = []
+        for i, d in enumerate(devs):
+            with obs.span("device_loader.shard_put") as sp:
+                rows = {}
+                for k, v in batch.items():
+                    per = int(v.shape[0]) // nd
+                    rows[k] = v[i * per : (i + 1) * per]
+                    if detach:
+                        rows[k] = np.array(rows[k], copy=True)
+                sp.n = sum(int(r.nbytes) for r in rows.values())
+                blocks.append({k: jax.device_put(r, d) for k, r in rows.items()})
+        # transfers must COMPLETE before the staging ring may recycle
+        jax.block_until_ready(blocks)
+        _SHARDS.add(nd)
+        return blocks
+
+    def _assemble(self, blocks: list) -> Dict[str, Any]:
+        """Per-device blocks → global ``jax.Array``s: quantized fields are
+        dequantized on each block's OWN device, then the blocks are declared
+        as the addressable shards of the ``(host_count * local_B, ...)``
+        global array. The assembly itself is metadata-only — no gather, no
+        cross-host traffic."""
+        jax = self._jax
+        quant = getattr(self.loader.ds, "quant", {})
+        host_count = self.loader.mesh.host_count
+        out: Dict[str, Any] = {}
+        for k in blocks[0]:
+            shards = [b[k] for b in blocks]
+            if k in quant:
                 from ..kernels import ops  # deferred: pallas import is heavy
 
+                params = [self._quant_params_on(d)[k] for d in self._gdevices]
                 shards = [
-                    ops.dequant_u8(
-                        s, *self._quant_params_on(d)[k][:2],
-                        out_dtype=self._quant_params_on(d)[k][2],
-                        interpret=self._interpret,
-                    )
-                    for s, d in zip(shards, devs)
+                    ops.dequant_u8(s, scale, bias, out_dtype=out_dtype,
+                                   interpret=self._interpret)
+                    for s, (scale, bias, out_dtype) in zip(shards, params)
                 ]
-            gshape = (n * host_count,) + tuple(shards[0].shape[1:])
+            gshape = (len(shards) * int(shards[0].shape[0]) * host_count,) + tuple(
+                shards[0].shape[1:])
             out[k] = jax.make_array_from_single_device_arrays(
                 gshape, self._gsharding, shards
             )

@@ -50,6 +50,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..core import obs
 from ..core.spec import RawArrayError, env_int, env_str
 from ..fleet.router import HashRing
 
@@ -172,9 +173,10 @@ class EpochPlan:
         self._row_offset = np.concatenate(
             [[0], np.cumsum(self.shard_rows)]
         ).astype(np.int64)
-        self._perm = shard_perm(self.seed, self.epoch, len(self.shard_rows), shuffle)
         self._wperm: Dict[int, np.ndarray] = {}  # shard -> within_perm memo
-        self._build()
+        with obs.span("data_mesh.plan", self.total_rows):
+            self._perm = shard_perm(self.seed, self.epoch, len(self.shard_rows), shuffle)
+            self._build()
 
     # -- schedule construction -------------------------------------------
 
@@ -390,7 +392,7 @@ class DataMesh:
 
 
 # -------------------------------------------------------------------------
-# global-array assembly (jax deferred: the mesh schedule itself is numpy)
+# the data axis's sharding (jax deferred: the mesh schedule itself is numpy)
 # -------------------------------------------------------------------------
 
 
@@ -405,56 +407,6 @@ def data_sharding(axis_name: str = "data"):
 
     devs = np.array(jax.devices())
     return NamedSharding(Mesh(devs, (axis_name,)), PartitionSpec(axis_name))
-
-
-def make_global_batch(
-    local_fields: Dict[str, Any],
-    host_count: int,
-    *,
-    sharding=None,
-    local_devices=None,
-    detach: bool = False,
-):
-    """Assemble this host's local batch into global ``jax.Array``s via
-    ``jax.make_array_from_single_device_arrays``: each field's local rows
-    split across this host's ``local_devices`` along axis 0, declared as
-    the addressable shards of a ``(host_count * local_B, ...)`` global
-    array. The result feeds ``distributed.steps`` factories unchanged —
-    a train step sees one logical batch sharded over the ``data`` axis.
-
-    Requires one process per mesh host (``jax.process_count() ==
-    host_count``); ``detach=True`` copies rows out of a reused staging
-    ring before the transfer."""
-    import jax
-
-    if local_devices is None:
-        local_devices = jax.local_devices()
-    if sharding is None:
-        sharding = data_sharding()
-    nd = len(local_devices)
-    out: Dict[str, Any] = {}
-    for name, v in local_fields.items():
-        n = int(v.shape[0])
-        if n % nd:
-            raise RawArrayError(
-                f"{name}: local batch of {n} rows does not split over "
-                f"{nd} local devices"
-            )
-        per = n // nd
-        shards = [
-            jax.device_put(
-                np.array(v[i * per : (i + 1) * per], copy=True)
-                if detach
-                else v[i * per : (i + 1) * per],
-                d,
-            )
-            for i, d in enumerate(local_devices)
-        ]
-        gshape = (n * host_count,) + tuple(v.shape[1:])
-        out[name] = jax.make_array_from_single_device_arrays(
-            gshape, sharding, shards
-        )
-    return out
 
 
 # -------------------------------------------------------------------------

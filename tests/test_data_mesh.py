@@ -4,6 +4,7 @@ host-agnostic global shuffle, mid-epoch repartition (join AND leave)
 preserving exactly-once, owned-shards-only I/O, elastic-state resume,
 lockstep steps_per_epoch, stats aggregation, and global-array assembly."""
 
+import json
 import os
 import subprocess
 import sys
@@ -399,3 +400,153 @@ def test_global_assembly_simulated_four_hosts(rid_root):
         env=env, capture_output=True, text=True, timeout=300,
     )
     assert out.returncode == 0 and "SIM_OK" in out.stdout, (out.stdout, out.stderr)
+
+
+# ---- the data-parallel image feed against its plain reference ---------------
+
+_FEED = r"""
+import importlib.util, json, os, sys
+os.environ["XLA_FLAGS"] = (
+    "--xla_force_host_platform_device_count=4 " + os.environ.get("XLA_FLAGS", "")
+)
+import numpy as np, jax
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from repro.core import QuantInfo
+from repro.data import DataLoader, DeviceLoader, RaDataset
+from repro.data.dataset import DatasetBuilder
+from repro.distributed import data_mesh
+from repro.distributed.data_mesh import DataMesh
+
+repo, root = sys.argv[1], sys.argv[2]
+spec = importlib.util.spec_from_file_location(
+    "dp4_reference", os.path.join(repo, "bench", "configs", "imagenet_u8_dp4.py"))
+ref = importlib.util.module_from_spec(spec)
+spec.loader.exec_module(ref)
+with open(os.path.join(repo, "bench", "configs", "imagenet_u8_dp4.json")) as f:
+    cfg = json.load(f)
+# 64 rows of 8x8x3 u8 codes in shards of 16; a global batch of 16, 4 a device
+cfg.update({"image": [8, 8, 3], "images": 64, "shard_rows": 16, "classes": 10})
+SEED, B = 2147483907, 16
+scale, bias = ref.scale_bias(cfg)
+b = DatasetBuilder(root, {"image": ((8, 8, 3), "float32"), "label": ((), "int32")},
+                   shard_rows=16, quantize={"image": QuantInfo(scale=scale, bias=bias,
+                                                               orig_dtype="float32")})
+for block in range(4):
+    rows = np.arange(16 * block, 16 * (block + 1))
+    b.append(image=ref.dequant(ref.block_codes(cfg, SEED, block), cfg), label=ref.labels(cfg, rows))
+b.finish()
+devices = jax.devices()
+assert len(devices) == 4, devices
+
+
+def read(batch):
+    labels = {s.device: s for s in batch["label"].addressable_shards}
+    return [(devices.index(s.device), s.index[0].start or 0,
+             labels[s.device].index[0].start or 0, np.asarray(s.data),
+             np.asarray(labels[s.device].data)) for s in batch["image"].addressable_shards]
+
+
+def checks():
+    # two epochs of global batches, each compared shard by shard; the
+    # second epoch's labels counted against the set's
+    dl = DeviceLoader(DataLoader(RaDataset(root), B, seed=SEED, mesh=DataMesh("host0", ["host0"])),
+                      interpret=True)
+    spe = dl.steps_per_epoch()
+    rows = ref.Rows(cfg, SEED)
+    worst = {"image_err": 0.0, "rows_repeated": 0.0, "shard_misplaced": 0.0}
+    epoch = []
+    for _ in range(2 * spe):
+        batch = next(dl)
+        assert batch["image"].shape == (B, 8, 8, 3) and batch["label"].shape == (B,)
+        r = ref.compare_batch(read(batch), rows, cfg, len(devices), B)
+        worst = {k: max(v, r[k]) for k, v in worst.items()}
+        if batch["_state"].epoch == 1:
+            epoch.append(np.asarray(batch["label"]))
+    dl.stop()
+    return {**worst, "epoch_rows_wrong": ref.epoch_rows_wrong(epoch, cfg)}
+
+
+out = {"sound": checks()}
+sharding, nxt = data_mesh.data_sharding, DataLoader.__next__
+
+
+def swapped(axis_name="data"):
+    # devices 0 and 1 exchange places in the mesh the global arrays name
+    d = jax.devices()
+    return NamedSharding(Mesh(np.array([d[1], d[0]] + d[2:]), (axis_name,)),
+                         PartitionSpec(axis_name))
+
+
+def repeated(self):
+    b = nxt(self)
+    for k in ("image", "label"):
+        b[k] = b[k].copy()
+        b[k][B // 4] = b[k][0]  # device 1's first row is device 0's first
+    return b
+
+
+first = {}
+
+
+def dropped(self):
+    b = nxt(self)
+    st = b["_state"]
+    if st.step == 0:
+        first[st.epoch] = {k: b[k].copy() for k in ("image", "label")}
+    elif st.step == 1:
+        # step 1's first row becomes a row of step 0 under another label
+        f = first[st.epoch]
+        j = int(np.flatnonzero(f["label"] != b["label"][0])[0])
+        for k in ("image", "label"):
+            b[k] = b[k].copy()
+            b[k][0] = f[k][j]
+    return b
+
+
+data_mesh.data_sharding = swapped
+out["two shards swapped"] = checks()
+data_mesh.data_sharding = sharding
+for name, fault in (("one row repeated across shards", repeated),
+                    ("one row dropped from an epoch", dropped)):
+    DataLoader.__next__ = fault
+    out[name] = checks()
+DataLoader.__next__ = nxt
+print("FEED " + json.dumps(out))
+"""
+
+# the cell's limits: image_err in quantization steps (0.25 on the chip; the
+# CPU's float32 reads about 1e-5), every count 0
+_FEED_LIMITS = {"image_err": 0.01, "rows_repeated": 0, "shard_misplaced": 0, "epoch_rows_wrong": 0}
+
+
+@pytest.fixture(scope="module")
+def feed_checks(tmp_path_factory):
+    pytest.importorskip("jax")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    src = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(filter(None, [src, env.get("PYTHONPATH", "")]))
+    out = subprocess.run(
+        [sys.executable, "-c", _FEED, repo, str(tmp_path_factory.mktemp("feed") / "images")],
+        env=env, capture_output=True, text=True, timeout=300,
+    )
+    lines = [ln for ln in out.stdout.splitlines() if ln.startswith("FEED ")]
+    assert out.returncode == 0 and lines, (out.stdout, out.stderr[-4000:])
+    return json.loads(lines[-1][5:])
+
+
+def test_global_image_feed_passes_the_data_parallel_reference(feed_checks):
+    """A one-host DataMesh over four devices: DeviceLoader's global arrays
+    hold distinct rows, each on the device of its global position, each
+    decoding to the image its label names, and an epoch delivers every row
+    once (``bench/configs/imagenet_u8_dp4.py``)."""
+    got = feed_checks["sound"]
+    assert all(got[k] <= lim for k, lim in _FEED_LIMITS.items()), got
+
+
+@pytest.mark.parametrize("fault,check", [("two shards swapped", "shard_misplaced"),
+                                         ("one row repeated across shards", "rows_repeated"),
+                                         ("one row dropped from an epoch", "epoch_rows_wrong")])
+def test_global_image_feed_fault_fails_the_reference(feed_checks, fault, check):
+    got = feed_checks[fault]
+    assert got[check] > _FEED_LIMITS[check], got

@@ -257,3 +257,38 @@ def test_feed_spans_match_the_loader_timers(image_root, shuffle, read):
     gathers = of(read, producer)
     assert len(gathers) == len(produced)
     assert all(g.parent in {p.id for p in produced} for g in gathers)
+
+
+def test_global_feed_spans_per_device_and_the_mesh_plan(image_root):
+    """A mesh loader's global assembly: one ``device_loader.shard_put`` per
+    local device inside each ``device_loader.h2d`` (``n`` = that device's
+    bytes), the dequant dispatch in ``device_loader.dequant`` beside it, as
+    on one device, and ``data_mesh.plan`` around each epoch's plan."""
+    import jax
+
+    from repro.distributed.data_mesh import DataMesh
+
+    shards = obs.counter("device_loader.shards")
+    t0, shards0 = obs.now(), shards.value
+    dl = DeviceLoader(DataLoader(RaDataset(image_root), 16, seed=3,
+                                 mesh=DataMesh("h", ["h"])), interpret=True)
+    try:
+        for _ in range(4):
+            next(dl)
+        feeder, producer = dl._thread.ident, dl.loader._thread.ident
+    finally:
+        dl.stop()
+    stats = dl.stats()
+    spans = _since(t0)
+    nd = len(jax.local_devices())
+    h2d = [s for s in spans if s.name == "device_loader.h2d" and s.thread == feeder]
+    puts = [s for s in spans if s.name == "device_loader.shard_put"]
+    assert len(h2d) == stats["h2d_batches"] >= 4
+    assert len(puts) == nd * len(h2d) == shards.value - shards0
+    assert {p.parent for p in puts} == {h.id for h in h2d}
+    assert all(p.n == 16 // nd * (6 * 6 * 3 + 4) for p in puts)
+    dequant = [s for s in spans if s.name == "device_loader.dequant" and s.thread == feeder]
+    assert len(dequant) >= len(h2d) - 1 and not {d.parent for d in dequant} & {h.id for h in h2d}
+    plans = [s for s in spans if s.name == "data_mesh.plan"]
+    assert plans and all(p.n == 250 for p in plans)
+    assert any(p.thread == producer for p in plans)
