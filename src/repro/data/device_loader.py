@@ -15,7 +15,10 @@ paper's read-bandwidth win evaporates at the device boundary.
   4× fewer bytes than float32 — and are decoded ON DEVICE by the fused
   Pallas kernel ``repro.kernels.ops.dequant_u8`` (one HBM read of the u8
   codes, fused ``q*scale + bias``); the wrapped loader's host-side
-  dequantization is turned off automatically;
+  dequantization is turned off automatically. A field whose minor axis is
+  not a multiple of 128 lanes (RGB pixels) crosses in the kernel's packed
+  2-D view (``packed_width``), so neither the host nor the device relays
+  it out; only the decoded float result is reshaped back;
 * ``stats()`` folds ``h2d_s`` (time inside device transfers), ``h2d_bytes``
   (bytes actually moved) and ``device_wait_s`` (consumer starved on the
   device queue) into the wrapped loader's counters, so the train loop's
@@ -44,9 +47,10 @@ Usage (flag-gated in ``repro.launch.train`` via ``--device-feed``)::
 
 from __future__ import annotations
 
+import math
 import queue
 import threading
-from typing import Any, Dict, Iterator, Optional, Tuple
+from typing import Any, Dict, Iterator, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -56,6 +60,46 @@ from .loader import DataLoader, LoaderState
 
 # device blocks the global-assembly path put, one per local device a batch
 _SHARDS = obs.counter("device_loader.shards")
+# quantized field blocks put in the packed view, one per field per device a batch
+_PACKED = obs.counter("device_loader.packed")
+
+
+def packed_width(shape: Tuple[int, ...]) -> int:
+    """Lanes of the packed ``(n / width, width)`` view in which a u8 block of
+    ``shape`` crosses the link: ``lcm(C, 128)`` for a minor axis ``C`` that
+    is not a multiple of 128 when the element count divides it, else 0 (the
+    block crosses as it is). The test ``ops.dequant_u8`` applies on the
+    device, so the kernel sees the same ``(rows, width)`` call either way."""
+    c = int(shape[-1])
+    width = math.lcm(c, 128)
+    n = math.prod(shape)
+    return width if c % 128 and n and n % width == 0 else 0
+
+
+def _wire(quant, field: str, v: np.ndarray) -> np.ndarray:
+    """The view of host block ``v`` of ``field`` that crosses the link: a
+    quantized field with a narrow minor axis as the kernel's packed
+    ``(n / width, width)`` (a free reshape of the C-contiguous block),
+    counted in ``device_loader.packed``; any other field as it is."""
+    width = packed_width(v.shape) if field in quant else 0
+    if not width:
+        return v
+    _PACKED.add(1)
+    return v.reshape(-1, width)
+
+
+class _Dequant(NamedTuple):
+    """A quantized field's kernel parameters on one device: ``(C,)`` float32
+    scale and bias for a block that crosses as it is, and the same tiled to
+    ``lcm(C, 128)`` lanes for one in the packed view (None where ``C`` is a
+    multiple of 128)."""
+
+    scale: Any
+    bias: Any
+    packed_scale: Any
+    packed_bias: Any
+    out_dtype: np.dtype
+    row_shape: Tuple[int, ...]
 
 
 def default_device_bufs() -> int:
@@ -113,8 +157,7 @@ class DeviceLoader:
         self.bufs = max(1, bufs if bufs is not None else default_device_bufs())
         self.device = device
         self._interpret = interpret
-        self._quant_dev: Dict[str, Tuple[Any, Any, np.dtype]] = {}
-        self._quant_dev_on: Dict[Any, Dict[str, Tuple[Any, Any, np.dtype]]] = {}
+        self._quant_dev_on: Dict[Any, Dict[str, _Dequant]] = {}
         # Regression note (ralint guarded-by): the feeder thread writes the
         # h2d_* counters while the consumer writes _wait_s/_n_batches and
         # stats() reads both — previously with no lock anywhere.
@@ -129,25 +172,42 @@ class DeviceLoader:
         self._stop = threading.Event()
         self._exc: Optional[BaseException] = None
 
-    # ---- quantized-field kernel parameters ---------------------------------
-    def _quant_params(self) -> Dict[str, Tuple[Any, Any, np.dtype]]:
-        """Per-field ``(scale, bias, out_dtype)`` with scale/bias already on
-        device, built once: the dequant kernel wants ``(C,)`` float32 for
-        the last axis of each quantized field."""
-        if not self._quant_dev:
+    # ---- quantized fields: wire view and kernel parameters ------------------
+    def _quant_params_on(self, device) -> Dict[str, _Dequant]:
+        """Per-field dequant parameters on ``device`` (None: the default
+        device), built once. The global-assembly path commits them to each
+        shard's device, which keeps the fused kernel's dispatch there."""
+        per = self._quant_dev_on.get(device)
+        if per is None:
+            per = self._quant_dev_on[device] = {}
             for f, info in getattr(self.loader.ds, "quant", {}).items():
                 shape, _ = self.loader.ds.logical_spec(f)
                 if not shape:
                     raise RawArrayError(
                         f"quantized field {f!r} has a scalar row shape"
                     )
-                scale, bias = info.channel_params(int(shape[-1]))
-                self._quant_dev[f] = (
-                    self._jax.device_put(scale, self.device),
-                    self._jax.device_put(bias, self.device),
-                    np.dtype(info.orig_dtype),
+                c = int(shape[-1])
+                scale, bias = info.channel_params(c)
+                reps = math.lcm(c, 128) // c if c % 128 else 0
+                tiled = (np.tile(scale, reps), np.tile(bias, reps)) if reps else (None, None)
+                per[f] = _Dequant(
+                    *self._jax.device_put((scale, bias) + tiled, device),
+                    np.dtype(info.orig_dtype), tuple(int(d) for d in shape),
                 )
-        return self._quant_dev
+        return per
+
+    def _decode(self, x, p: _Dequant):
+        """The fused kernel on device block ``x`` of a quantized field; a
+        block that crossed packed is decoded as the 2-D array it is, and only
+        the float result is reshaped to its ``(rows,) + row_shape``."""
+        from ..kernels import ops  # deferred: pallas import is heavy
+
+        if x.shape[-1] == p.row_shape[-1]:
+            return ops.dequant_u8(x, p.scale, p.bias, out_dtype=p.out_dtype,
+                                  interpret=self._interpret)
+        out = ops.dequant_u8(x, p.packed_scale, p.packed_bias,
+                             out_dtype=p.out_dtype, interpret=self._interpret)
+        return out.reshape((-1,) + p.row_shape)
 
     # ---- feeder thread ------------------------------------------------------
     def _start(self) -> None:
@@ -167,6 +227,7 @@ class DeviceLoader:
         # detached first or the "device" batch changes under the consumer
         # when the ring recycles
         detach = bool(getattr(self.loader, "reuse_buffers", False))
+        quant = getattr(loader.ds, "quant", {})
 
         def run():
             while not stop.is_set():
@@ -178,9 +239,9 @@ class DeviceLoader:
                             moved = self._put_shards(batch, detach)
                         else:
                             moved = {
-                                k: jax.device_put(
-                                    np.array(v, copy=True) if detach else v, dev
-                                )
+                                k: jax.device_put(_wire(
+                                    quant, k, np.array(v, copy=True) if detach else v
+                                ), dev)
                                 for k, v in batch.items()
                             }
                             # the transfer must COMPLETE before the next host
@@ -216,29 +277,14 @@ class DeviceLoader:
         self._thread.start()
 
     # ---- global assembly (DESIGN.md §15) ------------------------------------
-    def _quant_params_on(self, device) -> Dict[str, Tuple[Any, Any, np.dtype]]:
-        """Per-field dequant parameters COMMITTED to ``device`` — committed
-        operands keep the fused kernel's dispatch on each shard's own device
-        in the global-assembly path."""
-        per = self._quant_dev_on.get(device)
-        if per is None:
-            per = self._quant_dev_on[device] = {}
-            for f, info in getattr(self.loader.ds, "quant", {}).items():
-                shape, _ = self.loader.ds.logical_spec(f)
-                scale, bias = info.channel_params(int(shape[-1]))
-                per[f] = (
-                    self._jax.device_put(scale, device),
-                    self._jax.device_put(bias, device),
-                    np.dtype(info.orig_dtype),
-                )
-        return per
-
     def _put_shards(self, batch: Dict[str, np.ndarray], detach: bool) -> list:
         """Split the local host batch's rows over this host's addressable
         devices and ``device_put`` each device's block of every field
-        (uint8 for quantized fields): one ``device_loader.shard_put`` span
-        per device around its puts, then one wait for every transfer. Returns
-        one ``{field: single-device array}`` per device, in device order."""
+        (uint8 for quantized fields, in ``_wire``'s view: a block of rows is
+        a contiguous slice, so its packed view is free too): one
+        ``device_loader.shard_put`` span per device around its puts, then one
+        wait for every transfer. Returns one ``{field: single-device array}``
+        per device, in device order."""
         jax = self._jax
         if self._gsharding is None:
             from ..distributed import data_mesh
@@ -247,6 +293,7 @@ class DeviceLoader:
             self._gdevices = jax.local_devices()
         devs = self._gdevices
         nd = len(devs)
+        quant = getattr(self.loader.ds, "quant", {})
         for k, v in batch.items():
             n = int(v.shape[0])
             if n % nd:
@@ -263,6 +310,7 @@ class DeviceLoader:
                     rows[k] = v[i * per : (i + 1) * per]
                     if detach:
                         rows[k] = np.array(rows[k], copy=True)
+                    rows[k] = _wire(quant, k, rows[k])
                 sp.n = sum(int(r.nbytes) for r in rows.values())
                 blocks.append({k: jax.device_put(r, d) for k, r in rows.items()})
         # transfers must COMPLETE before the staging ring may recycle
@@ -272,10 +320,11 @@ class DeviceLoader:
 
     def _assemble(self, blocks: list) -> Dict[str, Any]:
         """Per-device blocks → global ``jax.Array``s: quantized fields are
-        dequantized on each block's OWN device, then the blocks are declared
-        as the addressable shards of the ``(host_count * local_B, ...)``
-        global array. The assembly itself is metadata-only — no gather, no
-        cross-host traffic."""
+        dequantized on each block's OWN device (a packed block reshaped back
+        to its rows there), then the blocks are declared as the addressable
+        shards of the ``(host_count * local_B, ...)`` global array. The
+        assembly itself is metadata-only — no gather, no cross-host
+        traffic."""
         jax = self._jax
         quant = getattr(self.loader.ds, "quant", {})
         host_count = self.loader.mesh.host_count
@@ -283,14 +332,8 @@ class DeviceLoader:
         for k in blocks[0]:
             shards = [b[k] for b in blocks]
             if k in quant:
-                from ..kernels import ops  # deferred: pallas import is heavy
-
-                params = [self._quant_params_on(d)[k] for d in self._gdevices]
-                shards = [
-                    ops.dequant_u8(s, scale, bias, out_dtype=out_dtype,
-                                   interpret=self._interpret)
-                    for s, (scale, bias, out_dtype) in zip(shards, params)
-                ]
+                shards = [self._decode(s, self._quant_params_on(d)[k])
+                          for s, d in zip(shards, self._gdevices)]
             gshape = (len(shards) * int(shards[0].shape[0]) * host_count,) + tuple(
                 shards[0].shape[1:])
             out[k] = jax.make_array_from_single_device_arrays(
@@ -307,17 +350,9 @@ class DeviceLoader:
         (uint8 in HBM → float out; DESIGN.md §12). Runs on the feeder
         thread — dispatch and execution overlap the consumer's train step;
         jax compiled-function execution is thread-safe."""
-        quant = self._quant_params()
-        if not quant:
-            return
-        from ..kernels import ops  # deferred: pallas import is heavy
-
-        for f, (scale, bias, out_dtype) in quant.items():
+        for f, p in self._quant_params_on(self.device).items():
             if f in moved:
-                moved[f] = ops.dequant_u8(
-                    moved[f], scale, bias, out_dtype=out_dtype,
-                    interpret=self._interpret,
-                )
+                moved[f] = self._decode(moved[f], p)
 
     def __next__(self) -> Dict[str, Any]:
         if self._exc is not None:

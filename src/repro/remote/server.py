@@ -46,6 +46,7 @@ import argparse
 import http.server
 import json
 import os
+import socket
 import threading
 import time
 from typing import Dict, Optional, Tuple
@@ -589,7 +590,36 @@ class ArrayServer(http.server.ThreadingHTTPServer):
         # latency_s > 0 sleeps per request WITHOUT the lock — concurrent
         # network latency (requests in flight overlap), for mesh scaling
         self.latency_s = float(latency_s)
+        # accepted connections, so server_close() can end keep-alive ones
+        # whose handler threads would otherwise go on serving
+        self._conns: set = set()  # guarded-by: _conns_lock
+        self._conns_lock = threading.Lock()
         super().__init__(address, RangeRequestHandler)
+
+    def process_request(self, request, client_address) -> None:
+        """Record the accepted connection, then serve it on its own thread."""
+        with self._conns_lock:
+            self._conns.add(request)
+        super().process_request(request, client_address)
+
+    def shutdown_request(self, request) -> None:
+        """Forget the connection, then close it."""
+        with self._conns_lock:
+            self._conns.discard(request)
+        super().shutdown_request(request)
+
+    def server_close(self) -> None:
+        """Close the listening socket AND every open connection: a closed
+        server answers no further request, on a new connection or a
+        keep-alive one."""
+        super().server_close()
+        with self._conns_lock:
+            conns = list(self._conns)
+        for c in conns:
+            try:
+                c.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass
 
     @property
     def port(self) -> int:

@@ -550,3 +550,77 @@ def test_global_image_feed_passes_the_data_parallel_reference(feed_checks):
 def test_global_image_feed_fault_fails_the_reference(feed_checks, fault, check):
     got = feed_checks[fault]
     assert got[check] > _FEED_LIMITS[check], got
+
+
+# ---- a packed image field through the global assembly ------------------------
+
+_PACKED = r"""
+import json, os, sys
+os.environ["XLA_FLAGS"] = (
+    "--xla_force_host_platform_device_count=4 " + os.environ.get("XLA_FLAGS", "")
+)
+import numpy as np, jax
+from repro.core import QuantInfo, obs
+from repro.data import DataLoader, DeviceLoader, RaDataset
+from repro.data.dataset import DatasetBuilder
+from repro.distributed import data_mesh
+from repro.distributed.data_mesh import DataMesh
+
+root = sys.argv[1]
+# scales of few significant bits: q*scale is exact in float32, so the host's
+# multiply-then-add and a fused multiply-add agree bit for bit
+scale = np.array([3, 5, 7], np.float32) / 1024
+bias = np.array([-2.1, -1.9, 0.4], np.float32)
+codes = np.random.default_rng(8).integers(0, 256, (64, 8, 8, 3)).astype(np.float32)
+b = DatasetBuilder(root, {"image": ((8, 8, 3), "float32"), "label": ((), "int32")},
+                   shard_rows=16, quantize={"image": QuantInfo(scale=scale, bias=bias,
+                                                               orig_dtype="float32")})
+b.append(image=codes * scale + bias, label=np.arange(64, dtype=np.int32))
+b.finish()
+devices = jax.devices()
+assert len(devices) == 4, devices
+B, per = 16, 4  # a device's block: 4 x 8x8x3 = 768 codes = 2 rows of 384 lanes
+packed = obs.counter("device_loader.packed")
+host = DataLoader(RaDataset(root), B, seed=9, mesh=DataMesh("host0", ["host0"]))
+dl = DeviceLoader(DataLoader(RaDataset(root), B, seed=9, mesh=DataMesh("host0", ["host0"])),
+                  interpret=True)
+before = packed.value
+shards = 0
+for _ in range(6):
+    want, got = next(host), next(dl)
+    for k in ("image", "label"):
+        g = got[k]
+        assert g.shape == want[k].shape and g.dtype == want[k].dtype, (k, g.shape, g.dtype)
+        assert g.sharding == data_mesh.data_sharding(), g.sharding
+        for s in g.addressable_shards:
+            i = devices.index(s.device)
+            assert np.arange(B)[s.index[0]].tolist() == list(range(per * i, per * (i + 1)))
+            assert np.array_equal(np.asarray(s.data), want[k][s.index]), (k, i)
+            shards += 1
+dl.stop()
+host.stop()
+print("PACKED " + json.dumps({"packed": packed.value - before, "shards": shards,
+                              "puts": dl.stats()["h2d_batches"]}))
+"""
+
+
+def test_global_assembly_of_a_packed_image_field(tmp_path):
+    """On four devices an 8x8x3 u8 image field crosses as each device's
+    packed ``(2, 384)`` block, is decoded there and reshaped back: the
+    global arrays equal the host loader's dequantized batch shard by shard
+    (``addressable_shards`` index and values) with the same shape, dtype
+    and sharding, and ``device_loader.packed`` counts one block per device
+    per batch."""
+    pytest.importorskip("jax")
+    src = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(filter(None, [src, env.get("PYTHONPATH", "")]))
+    out = subprocess.run(
+        [sys.executable, "-c", _PACKED, str(tmp_path / "images")],
+        env=env, capture_output=True, text=True, timeout=300,
+    )
+    lines = [ln for ln in out.stdout.splitlines() if ln.startswith("PACKED ")]
+    assert out.returncode == 0 and lines, (out.stdout, out.stderr[-4000:])
+    got = json.loads(lines[-1][7:])
+    assert got["shards"] == 6 * 2 * 4
+    assert got["puts"] >= 6 and got["packed"] == 4 * got["puts"], got

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 import repro.core as ra
+from repro.core import obs
 from repro.data import (
     DataLoader,
     DatasetBuilder,
@@ -305,6 +306,87 @@ def test_device_loader_moves_quantized_bytes(quant_root):
     # image moves as u8 codes (108 B/row) + int32 label: 4x less than f32
     assert per_batch == 16 * (6 * 6 * 3 + 4)
     assert {"h2d_s", "device_wait_s", "device_batches"} <= set(s)
+
+
+# ------------------------------- narrow minor axis: the kernel's packed view
+# Per-channel scales of few significant bits make every q*scale exact in
+# float32, so the host's multiply-then-add and a backend's fused
+# multiply-add round alike and the comparison can be bitwise: what it
+# guards is the packed layout (channel and row order), where a fault
+# shows as a whole wrong value.
+_PACK_CASES = {
+    # 8 rows x 16x16x3 = 6144 codes = 16 rows of lcm(3, 128) = 384 lanes
+    "packed": ((16, 16, 3), 1),
+    # 8 rows x 5x5x3 = 600 codes: not a multiple of 384, crosses as it is
+    "fallback": ((5, 5, 3), 0),
+    # a minor axis of 128 is whole lanes already
+    "wide": ((4, 128), 0),
+}
+
+
+@pytest.fixture(scope="module")
+def pack_roots(tmp_path_factory):
+    base = tmp_path_factory.mktemp("pack")
+    rng = np.random.default_rng(5)
+    roots = {}
+    for name, (shape, _) in _PACK_CASES.items():
+        c = shape[-1]
+        scale = (np.arange(c, dtype=np.float32) % 7 + 3) / 1024
+        bias = np.linspace(-2.1, 1.3, c).astype(np.float32)
+        roots[name] = root = str(base / name)
+        b = DatasetBuilder(
+            root, {"image": (shape, "float32"), "label": ((), "int32")},
+            shard_rows=24,
+            quantize={"image": ra.QuantInfo(scale=scale, bias=bias, orig_dtype="float32")},
+        )
+        codes = rng.integers(0, 256, (72,) + shape).astype(np.float32)
+        b.append(image=codes * scale + bias, label=np.arange(72, dtype=np.int32))
+        b.finish()
+    return roots
+
+
+@pytest.mark.parametrize("reuse", [False, True])
+@pytest.mark.parametrize("case", list(_PACK_CASES))
+def test_device_loader_packed_view_matches_host(pack_roots, case, reuse):
+    """A u8 image field whose batch fills whole ``(n, 384)`` rows crosses
+    packed and decodes to the host's dequantization bit for bit, in its
+    logical shape; one that does not, or whose minor axis is 128 wide,
+    crosses as it is. ``device_loader.packed`` counts one per batch put
+    in the first case and none in the others."""
+    shape, per_batch = _PACK_CASES[case]
+    root = pack_roots[case]
+    packed = obs.counter("device_loader.packed")
+    host = DataLoader(RaDataset(root), 8, seed=11)
+    dev = DeviceLoader(DataLoader(RaDataset(root), 8, seed=11, reuse_buffers=reuse))
+    before = packed.value
+    try:
+        for _ in range(5):
+            hb, db = next(host), next(dev)
+            assert hb["_state"].__dict__ == db["_state"].__dict__
+            for f in ("image", "label"):
+                da = np.asarray(db[f])
+                assert da.shape == hb[f].shape and da.dtype == hb[f].dtype, f
+                assert np.array_equal(da, hb[f]), f
+            assert db["image"].shape == (8,) + shape
+    finally:
+        host.stop()
+        dev.stop()
+    puts = dev.stats()["h2d_batches"]
+    assert puts >= 5
+    assert packed.value - before == per_batch * puts
+
+
+def test_packed_width_is_the_kernels_repacking_rule():
+    from repro.data.device_loader import packed_width
+
+    assert packed_width((256, 224, 224, 3)) == 384      # (100352, 384)
+    assert packed_width((8, 16, 16, 3)) == 384
+    assert packed_width((8, 5, 5, 3)) == 0               # 600 codes
+    assert packed_width((8, 4, 128)) == 0                # whole lanes
+    assert packed_width((8, 256)) == 0
+    assert packed_width((4, 8, 8, 3)) == 384             # one device's block
+    assert packed_width((16, 96)) == 384                 # lcm(96, 128)
+    assert packed_width((0, 16, 16, 3)) == 0             # empty block
 
 
 def test_device_loader_restore_exact(token_root):

@@ -74,6 +74,27 @@ def test_range_request_semantics(served):
     assert ei.value.code == 304
 
 
+def test_server_close_ends_keep_alive_connections(tmp_path):
+    """A closed server answers nothing more, not even on a connection it
+    accepted before: keep-alive handler threads must not outlive it."""
+    import http.client
+
+    _write(str(tmp_path), "x.ra", np.arange(256, dtype=np.uint8))
+    server = remote.serve(str(tmp_path), port=0)
+    conn = http.client.HTTPConnection("127.0.0.1", server.port, timeout=10)
+    try:
+        conn.request("GET", "/x.ra", headers={"Range": "bytes=0-15"})
+        resp = conn.getresponse()
+        assert resp.status == 206 and len(resp.read()) == 16
+        server.shutdown()
+        server.server_close()
+        with pytest.raises((http.client.HTTPException, OSError)):
+            conn.request("GET", "/x.ra", headers={"Range": "bytes=16-31"})
+            conn.getresponse().read()
+    finally:
+        conn.close()
+
+
 def test_unsatisfiable_range_is_416(served):
     root, base = served
     _write(root, "x.ra", np.zeros(8, np.uint8))
