@@ -23,7 +23,7 @@ of the checkout, which is removed at the end. Phases, in order:
               (32x32x3, batch 256) against ``DataLoader(dequant=True)``.
 
 With ``--chips 4`` the only phase is the mesh: a data-parallel OLMo-1B step
-fed by ``DeviceLoader(global_arrays=True)`` over a one-host ``DataMesh``,
+fed by a global-array ``DeviceLoader`` over a one-host ``DataMesh``,
 against the same global batch on one device, plus a sharded
 ``restore_pipelined`` against the one-device restore.
 
@@ -268,7 +268,6 @@ def run_mesh(cfg, workdir: str, seed: int) -> None:
     # -- data-parallel step vs one device, same global batch ---------------
     loader = DeviceLoader(
         DataLoader(RaDataset(token_dataset(workdir, cfg, seed)), BATCH, seed=seed, mesh=mesh_of_one_host),
-        global_arrays=True,
     )
     batch = next(loader)
     batch.pop("_state")
@@ -329,8 +328,7 @@ def run_mesh(cfg, workdir: str, seed: int) -> None:
 
     # -- quantized field: each shard dequantized on its own device ---------
     root = image_dataset(workdir, seed, IMAGE_BATCH)
-    dev = DeviceLoader(DataLoader(RaDataset(root), IMAGE_BATCH, seed=seed, mesh=mesh_of_one_host),
-                       global_arrays=True)
+    dev = DeviceLoader(DataLoader(RaDataset(root), IMAGE_BATCH, seed=seed, mesh=mesh_of_one_host))
     host = DataLoader(RaDataset(root), IMAGE_BATCH, seed=seed, mesh=mesh_of_one_host, dequant=True)
     try:
         d, h = next(dev), next(host)

@@ -22,14 +22,14 @@ module overlaps them so the total approaches the MAX:
 3. **overlapped device upload** — whichever pool thread completes a leaf
    dispatches its ``jax.device_put`` (and, for quantized-u8 leaves
    restoring onto a single device, the fused Pallas ``dequant_u8`` —
-   uint8 crosses the link, floats materialize device-side exactly as the
-   device feed plane does for batches) WITHOUT blocking, while later
-   leaves are still being fetched/decoded; one quiet barrier at the end
-   waits for every transfer at once.
+   uint8 crosses the link, floats materialize device-side through the same
+   ``DeviceDequant`` the device feed plane uses for batches) WITHOUT
+   blocking, while later leaves are still being fetched/decoded; one quiet
+   barrier at the end waits for every transfer at once.
 
 The phase-by-phase path survives as :func:`restore_naive` — the benchmark
-baseline (`benchmarks/bench_coldstart.py`) and the escape hatch
-(`--restore naive`).
+baseline (`benchmarks/bench_coldstart.py`) and the tests' reference. Both
+put every leaf through :func:`_put_leaf`.
 """
 
 from __future__ import annotations
@@ -297,6 +297,31 @@ def _entry_quant_hint(entry: Dict[str, Any]) -> Any:
         return None
 
 
+def _decoded_on_device(quant: Any, sharding: Any, shape: Tuple[int, ...]) -> bool:
+    """Whether a quantized leaf crosses as u8 and is decoded on its one
+    target device by the fused kernel. A sharded leaf is decoded on the
+    host (the kernel path puts one block on one device), and so is a 0-d
+    leaf (it has no minor axis for the kernel's channels)."""
+    return quant is not None and sharding is None and bool(shape)
+
+
+def _put_leaf(arr: np.ndarray, quant: Any, sharding: Any, device: Any,
+              interpret: Optional[bool]) -> Tuple[Any, int]:
+    """Put one leaf's stored payload on its device(s) without waiting for
+    the transfer, decoding a quantized leaf where :func:`_decoded_on_device`
+    says. Returns the array and the host bytes put."""
+    import jax
+
+    if _decoded_on_device(quant, sharding, arr.shape):
+        from ..kernels.device_dequant import DeviceDequant  # deferred: pallas is heavy
+
+        dq = DeviceDequant(quant, arr.shape, interpret=interpret)
+        return dq.to_device(arr, device), int(arr.nbytes)
+    if quant is not None:
+        arr = quant.dequantize(arr)
+    return jax.device_put(arr, sharding if sharding is not None else device), int(arr.nbytes)
+
+
 def _start_warmup(
     plans: List[_LeafPlan], interpret: Optional[bool]
 ) -> Optional[Tuple[threading.Thread, List[BaseException]]]:
@@ -310,7 +335,7 @@ def _start_warmup(
     fails the restore."""
     shapes = {}
     for p in plans:
-        if p.quant is not None and p.sharding is None and p.want:
+        if _decoded_on_device(p.quant, p.sharding, p.want):
             shapes[(p.want, str(p.quant.orig_dtype))] = None
     if not shapes:
         return None
@@ -319,25 +344,15 @@ def _start_warmup(
 
     def run() -> None:
         try:
-            import jax
-            import jax.numpy as jnp
-
-            from ..kernels import ops
+            from ..kernels.device_dequant import compile_decode
 
             with obs.under(parent), obs.span("restore.warmup", len(shapes)):
                 for shape, dt in shapes:
-                    c = int(shape[-1])
-                    # AOT lower+compile only, through the same jit entry (and
-                    # so the same tile sizing) as the upload path: executing
-                    # a full-size dummy would burn a leaf's worth of device
-                    # time and park this thread in block_until_ready,
-                    # GIL-convoying against the fetch wave
-                    ops.dequant_u8.lower(
-                        jax.ShapeDtypeStruct(shape, jnp.uint8),
-                        jax.ShapeDtypeStruct((c,), jnp.float32),
-                        jax.ShapeDtypeStruct((c,), jnp.float32),
-                        out_dtype=jnp.dtype(dt), interpret=interpret,
-                    ).compile()
+                    # AOT lower+compile only, of the very call the upload
+                    # path makes: executing a full-size dummy would burn a
+                    # leaf's worth of device time and park this thread in
+                    # block_until_ready, GIL-convoying against the fetch wave
+                    compile_decode(shape, dt, interpret=interpret)
         except BaseException as e:  # noqa: BLE001 — re-raised by restore_pipelined
             errors.append(e)
 
@@ -444,13 +459,14 @@ def _restore_pipelined(
             )
             # budget/scheduling cost is knowable from the manifest alone:
             # leaves hold their STORED element width host-side (u8 for
-            # quantized), except sharded quantized leaves which dequantize
-            # on the host and so hold the logical float footprint
+            # quantized), except quantized leaves decoded on the host,
+            # which hold the logical float footprint
             elems = int(np.prod(want, dtype=np.int64)) if want else 1
             if plan.quant is not None:
                 out_itemsize = np.dtype(plan.quant.orig_dtype).itemsize
                 st.logical_bytes += elems * out_itemsize
-                plan.cost = elems * (out_itemsize if sh is not None else 1)
+                on_device = _decoded_on_device(plan.quant, sh, want)
+                plan.cost = elems * (1 if on_device else out_itemsize)
             else:
                 logical = elems * np.dtype(like.dtype).itemsize
                 st.logical_bytes += logical
@@ -563,40 +579,12 @@ def _restore_pipelined(
             try:
                 _check_local_pin(plan)
                 with obs.under(plan.span_id), obs.span("restore.h2d") as sp:
-                    if plan.quant is not None and plan.sharding is not None:
-                        # multi-target leaf: host dequant, then shard-put
-                        arr = plan.quant.dequantize(arr)
-                        out = jax.device_put(arr, plan.sharding)
-                        dequant = True
-                    elif plan.quant is not None and plan.hdr.shape:
-                        # u8 over the link, fused dequant on device
-                        from ..kernels import ops  # deferred: pallas is heavy
-
-                        moved = jax.device_put(arr, device)
-                        c = int(plan.hdr.shape[-1])
-                        scale, bias = plan.quant.channel_params(c)
-                        if device is not None:
-                            # jit places uncommitted args on the DEFAULT
-                            # device; an explicit target needs explicit puts
-                            scale = jax.device_put(scale, device)
-                            bias = jax.device_put(bias, device)
-                        out = ops.dequant_u8(
-                            moved, scale, bias,
-                            out_dtype=np.dtype(plan.quant.orig_dtype), interpret=interpret,
-                        )
-                        dequant = True
-                    else:
-                        dequant = plan.quant is not None
-                        if dequant:  # 0-d quantized: host decode
-                            arr = plan.quant.dequantize(arr)
-                        out = jax.device_put(arr, plan.sharding if plan.sharding is not None else device)
-                    sp.n = int(arr.nbytes)
-                plan.out = out
+                    plan.out, nbytes = _put_leaf(arr, plan.quant, plan.sharding, device, interpret)
+                    sp.n = nbytes
                 with stats_lock:
                     st.h2d_s += sp.seconds
-                    st.h2d_bytes += int(arr.nbytes)
-                    if dequant:
-                        st.dequant_leaves += 1
+                    st.h2d_bytes += nbytes
+                    st.dequant_leaves += plan.quant is not None
             except BaseException as e:  # noqa: BLE001 — forwarded
                 _fail(e)
             finally:
@@ -732,10 +720,9 @@ def restore_naive(
 ) -> Tuple[Any, Any, Dict[str, Any]]:
     """Phase-by-phase restore: fetch + decode EVERY leaf to host first, THEN
     device_put (+ on-device dequant) leaf by leaf. Runs the exact same
-    per-leaf decode as :func:`restore_pipelined` — quantized leaves go
-    through the same fused device kernel — so the two paths are bit-exact
-    by construction and their difference is pure overlap. The benchmark
-    baseline and the escape hatch (``--restore naive``)."""
+    per-leaf put and decode as :func:`restore_pipelined` (:func:`_put_leaf`),
+    so the two paths are bit-exact by construction and their difference is
+    pure overlap. The benchmark baseline and the tests' reference."""
     import jax
 
     from .store import _read_leaves_parallel
@@ -771,27 +758,11 @@ def restore_naive(
                 arr.nbytes * np.dtype(quant.orig_dtype).itemsize if quant is not None else arr.nbytes
             )
             t0 = time.perf_counter()
-            if quant is not None and sh is not None:
-                out = jax.device_put(quant.dequantize(arr), sh)
-                st.dequant_leaves += 1
-            elif quant is not None and arr.shape:
-                from ..kernels import ops  # deferred: pallas is heavy
-
-                scale, bias = quant.channel_params(int(arr.shape[-1]))
-                out = ops.dequant_u8(
-                    jax.device_put(arr, device),
-                    jax.device_put(scale, device), jax.device_put(bias, device),
-                    out_dtype=np.dtype(quant.orig_dtype), interpret=interpret,
-                )
-                st.dequant_leaves += 1
-            else:
-                if quant is not None:  # 0-d quantized: host decode
-                    arr = quant.dequantize(arr)
-                    st.dequant_leaves += 1
-                out = jax.device_put(arr, sh if sh is not None else device)
+            out, nbytes = _put_leaf(arr, quant, sh, device, interpret)
             jax.block_until_ready(out)
             st.h2d_s += time.perf_counter() - t0
-            st.h2d_bytes += int(arr.nbytes)
+            st.h2d_bytes += nbytes
+            st.dequant_leaves += quant is not None
             moved.append(out)
         outs.append(jax.tree_util.tree_unflatten(treedef, moved))
 

@@ -14,11 +14,12 @@ paper's read-bandwidth win evaporates at the device boundary.
 * quantized fields (DESIGN.md §12) cross the PCIe/ICI link as uint8 —
   4× fewer bytes than float32 — and are decoded ON DEVICE by the fused
   Pallas kernel ``repro.kernels.ops.dequant_u8`` (one HBM read of the u8
-  codes, fused ``q*scale + bias``); the wrapped loader's host-side
-  dequantization is turned off automatically. A field whose minor axis is
-  not a multiple of 128 lanes (RGB pixels) crosses in the kernel's packed
-  2-D view (``packed_width``), so neither the host nor the device relays
-  it out; only the decoded float result is reshaped back;
+  codes, fused ``q*scale + bias``) through
+  ``repro.kernels.device_dequant.DeviceDequant``; the wrapped loader's
+  host-side dequantization is turned off automatically. A field whose minor
+  axis is not a multiple of 128 lanes (RGB pixels) crosses in the kernel's
+  packed 2-D view, so neither the host nor the device relays it out; only
+  the decoded float result is reshaped back;
 * ``stats()`` folds ``h2d_s`` (time inside device transfers), ``h2d_bytes``
   (bytes actually moved) and ``device_wait_s`` (consumer starved on the
   device queue) into the wrapped loader's counters, so the train loop's
@@ -47,10 +48,9 @@ Usage (flag-gated in ``repro.launch.train`` via ``--device-feed``)::
 
 from __future__ import annotations
 
-import math
 import queue
 import threading
-from typing import Any, Dict, Iterator, NamedTuple, Optional, Tuple
+from typing import Any, Dict, Iterator, List, Optional
 
 import numpy as np
 
@@ -58,48 +58,8 @@ from ..core import obs
 from ..core.spec import RawArrayError, env_int
 from .loader import DataLoader, LoaderState
 
-# device blocks the global-assembly path put, one per local device a batch
+# device blocks put, one per target device a batch
 _SHARDS = obs.counter("device_loader.shards")
-# quantized field blocks put in the packed view, one per field per device a batch
-_PACKED = obs.counter("device_loader.packed")
-
-
-def packed_width(shape: Tuple[int, ...]) -> int:
-    """Lanes of the packed ``(n / width, width)`` view in which a u8 block of
-    ``shape`` crosses the link: ``lcm(C, 128)`` for a minor axis ``C`` that
-    is not a multiple of 128 when the element count divides it, else 0 (the
-    block crosses as it is). The test ``ops.dequant_u8`` applies on the
-    device, so the kernel sees the same ``(rows, width)`` call either way."""
-    c = int(shape[-1])
-    width = math.lcm(c, 128)
-    n = math.prod(shape)
-    return width if c % 128 and n and n % width == 0 else 0
-
-
-def _wire(quant, field: str, v: np.ndarray) -> np.ndarray:
-    """The view of host block ``v`` of ``field`` that crosses the link: a
-    quantized field with a narrow minor axis as the kernel's packed
-    ``(n / width, width)`` (a free reshape of the C-contiguous block),
-    counted in ``device_loader.packed``; any other field as it is."""
-    width = packed_width(v.shape) if field in quant else 0
-    if not width:
-        return v
-    _PACKED.add(1)
-    return v.reshape(-1, width)
-
-
-class _Dequant(NamedTuple):
-    """A quantized field's kernel parameters on one device: ``(C,)`` float32
-    scale and bias for a block that crosses as it is, and the same tiled to
-    ``lcm(C, 128)`` lanes for one in the packed view (None where ``C`` is a
-    multiple of 128)."""
-
-    scale: Any
-    bias: Any
-    packed_scale: Any
-    packed_bias: Any
-    out_dtype: np.dtype
-    row_shape: Tuple[int, ...]
 
 
 def default_device_bufs() -> int:
@@ -123,7 +83,6 @@ class DeviceLoader:
         bufs: Optional[int] = None,
         device: Any = None,
         interpret: Optional[bool] = None,
-        global_arrays: Optional[bool] = None,
     ):
         import jax  # deferred: keep `repro.data` importable without jax
 
@@ -135,29 +94,22 @@ class DeviceLoader:
         self._jax = jax
         self.loader = loader
         mesh = getattr(loader, "mesh", None)
-        # a mesh loader assembles global jax.Arrays by default; override only
-        # to keep plain per-host arrays (e.g. non-collective eval loops)
-        self.global_arrays = (mesh is not None) if global_arrays is None else bool(global_arrays)
-        if self.global_arrays:
-            if mesh is None:
-                raise RawArrayError(
-                    "global_arrays=True requires DataLoader(mesh=...)"
-                )
-            if mesh.host_count > 1 and jax.process_count() != mesh.host_count:
-                raise RawArrayError(
-                    f"global assembly needs one jax process per mesh host: "
-                    f"mesh has {mesh.host_count} hosts but "
-                    f"jax.process_count()={jax.process_count()} (several "
-                    f"mesh hosts in one process take global_arrays=False)"
-                )
+        # a mesh loader's batches are assembled into global jax.Arrays
+        self.global_arrays = mesh is not None
+        if self.global_arrays and mesh.host_count > 1 and jax.process_count() != mesh.host_count:
+            raise RawArrayError(
+                f"global assembly needs one jax process per mesh host: "
+                f"mesh has {mesh.host_count} hosts but "
+                f"jax.process_count()={jax.process_count()}"
+            )
         self._gsharding: Any = None  # lazy data_mesh.data_sharding()
-        self._gdevices: Any = None
+        self._devices: Optional[List[Any]] = None  # lazy: the blocks' targets
         # device decode replaces host decode: raw uint8 over the wire
         loader.dequant = False
         self.bufs = max(1, bufs if bufs is not None else default_device_bufs())
         self.device = device
         self._interpret = interpret
-        self._quant_dev_on: Dict[Any, Dict[str, _Dequant]] = {}
+        self._dequant: Optional[Dict[str, Any]] = None  # field -> DeviceDequant
         # Regression note (ralint guarded-by): the feeder thread writes the
         # h2d_* counters while the consumer writes _wait_s/_n_batches and
         # stats() reads both — previously with no lock anywhere.
@@ -172,50 +124,11 @@ class DeviceLoader:
         self._stop = threading.Event()
         self._exc: Optional[BaseException] = None
 
-    # ---- quantized fields: wire view and kernel parameters ------------------
-    def _quant_params_on(self, device) -> Dict[str, _Dequant]:
-        """Per-field dequant parameters on ``device`` (None: the default
-        device), built once. The global-assembly path commits them to each
-        shard's device, which keeps the fused kernel's dispatch there."""
-        per = self._quant_dev_on.get(device)
-        if per is None:
-            per = self._quant_dev_on[device] = {}
-            for f, info in getattr(self.loader.ds, "quant", {}).items():
-                shape, _ = self.loader.ds.logical_spec(f)
-                if not shape:
-                    raise RawArrayError(
-                        f"quantized field {f!r} has a scalar row shape"
-                    )
-                c = int(shape[-1])
-                scale, bias = info.channel_params(c)
-                reps = math.lcm(c, 128) // c if c % 128 else 0
-                tiled = (np.tile(scale, reps), np.tile(bias, reps)) if reps else (None, None)
-                per[f] = _Dequant(
-                    *self._jax.device_put((scale, bias) + tiled, device),
-                    np.dtype(info.orig_dtype), tuple(int(d) for d in shape),
-                )
-        return per
-
-    def _decode(self, x, p: _Dequant):
-        """The fused kernel on device block ``x`` of a quantized field; a
-        block that crossed packed is decoded as the 2-D array it is, and only
-        the float result is reshaped to its ``(rows,) + row_shape``."""
-        from ..kernels import ops  # deferred: pallas import is heavy
-
-        if x.shape[-1] == p.row_shape[-1]:
-            return ops.dequant_u8(x, p.scale, p.bias, out_dtype=p.out_dtype,
-                                  interpret=self._interpret)
-        out = ops.dequant_u8(x, p.packed_scale, p.packed_bias,
-                             out_dtype=p.out_dtype, interpret=self._interpret)
-        return out.reshape((-1,) + p.row_shape)
-
     # ---- feeder thread ------------------------------------------------------
     def _start(self) -> None:
-        jax = self._jax
         q = self._q = queue.Queue(maxsize=self.bufs)
         stop = self._stop = threading.Event()
         self._exc = None
-        dev = self.device
         # captured by value: a zombie feeder that outlives its join timeout
         # keeps THIS loader object even after stop() swaps in a fresh one,
         # so it can never steal batches from (or poison the sticky-error
@@ -227,7 +140,6 @@ class DeviceLoader:
         # detached first or the "device" batch changes under the consumer
         # when the ring recycles
         detach = bool(getattr(self.loader, "reuse_buffers", False))
-        quant = getattr(loader.ds, "quant", {})
 
         def run():
             while not stop.is_set():
@@ -235,18 +147,7 @@ class DeviceLoader:
                     batch = next(loader)
                     state = batch.pop("_state", None)
                     with obs.span("device_loader.h2d") as sp:
-                        if self.global_arrays:
-                            moved = self._put_shards(batch, detach)
-                        else:
-                            moved = {
-                                k: jax.device_put(_wire(
-                                    quant, k, np.array(v, copy=True) if detach else v
-                                ), dev)
-                                for k, v in batch.items()
-                            }
-                            # the transfer must COMPLETE before the next host
-                            # batch may recycle the staging ring buffer under it
-                            jax.block_until_ready(list(moved.values()))
+                        blocks = self._put_blocks(batch, detach)
                         sp.n = nbytes = sum(int(v.nbytes) for v in batch.values())
                     with self._stats_lock:
                         self._h2d_s += sp.seconds
@@ -256,10 +157,7 @@ class DeviceLoader:
                     # the fused dequant here so the consumer's critical path
                     # is nothing but q.get() + train step
                     with obs.span("device_loader.dequant"):
-                        if self.global_arrays:
-                            moved = self._assemble(moved)
-                        else:
-                            self._dequant_on_device(moved)
+                        moved = self._decode_blocks(blocks)
                     item: Any = (moved, state)
                 except Exception as e:  # surface in consumer (sticky there)
                     item = e
@@ -276,24 +174,36 @@ class DeviceLoader:
         self._thread = threading.Thread(target=run, daemon=True, name="ra-h2d")
         self._thread.start()
 
-    # ---- global assembly (DESIGN.md §15) ------------------------------------
-    def _put_shards(self, batch: Dict[str, np.ndarray], detach: bool) -> list:
-        """Split the local host batch's rows over this host's addressable
-        devices and ``device_put`` each device's block of every field
-        (uint8 for quantized fields, in ``_wire``'s view: a block of rows is
-        a contiguous slice, so its packed view is free too): one
-        ``device_loader.shard_put`` span per device around its puts, then one
-        wait for every transfer. Returns one ``{field: single-device array}``
-        per device, in device order."""
-        jax = self._jax
-        if self._gsharding is None:
-            from ..distributed import data_mesh
+    def _decoders(self) -> Dict[str, Any]:
+        """A ``DeviceDequant`` per quantized field, built once."""
+        if self._dequant is None:
+            from ..kernels.device_dequant import DeviceDequant  # deferred: pallas is heavy
 
-            self._gsharding = data_mesh.data_sharding()
-            self._gdevices = jax.local_devices()
-        devs = self._gdevices
-        nd = len(devs)
-        quant = getattr(self.loader.ds, "quant", {})
+            self._dequant = {}
+            for f, info in getattr(self.loader.ds, "quant", {}).items():
+                shape, _ = self.loader.ds.logical_spec(f)
+                if not shape:
+                    raise RawArrayError(
+                        f"quantized field {f!r} has a scalar row shape"
+                    )
+                self._dequant[f] = DeviceDequant(info, (-1,) + tuple(shape),
+                                                 interpret=self._interpret)
+        return self._dequant
+
+    def _put_blocks(self, batch: Dict[str, np.ndarray], detach: bool) -> List[Dict[str, Any]]:
+        """Split the host batch's rows over the target devices (``device``
+        alone, or this host's addressable devices for a mesh loader) and
+        ``device_put`` each device's block of every field, quantized fields
+        as u8 in the kernel's view (a block of rows is a contiguous slice,
+        so a packed view is free): one ``device_loader.shard_put`` span per
+        device around its puts, then one wait for every transfer, which
+        must COMPLETE before the staging ring may recycle. Returns one
+        ``{field: single-device array}`` per device, in device order."""
+        jax = self._jax
+        if self._devices is None:
+            self._devices = jax.local_devices() if self.global_arrays else [self.device]
+        nd = len(self._devices)
+        dq = self._decoders()
         for k, v in batch.items():
             n = int(v.shape[0])
             if n % nd:
@@ -302,7 +212,7 @@ class DeviceLoader:
                     f"{nd} local devices"
                 )
         blocks = []
-        for i, d in enumerate(devs):
+        for i, d in enumerate(self._devices):
             with obs.span("device_loader.shard_put") as sp:
                 rows = {}
                 for k, v in batch.items():
@@ -310,30 +220,36 @@ class DeviceLoader:
                     rows[k] = v[i * per : (i + 1) * per]
                     if detach:
                         rows[k] = np.array(rows[k], copy=True)
-                    rows[k] = _wire(quant, k, rows[k])
                 sp.n = sum(int(r.nbytes) for r in rows.values())
-                blocks.append({k: jax.device_put(r, d) for k, r in rows.items()})
-        # transfers must COMPLETE before the staging ring may recycle
+                blocks.append({k: dq[k].put(r, d) if k in dq else jax.device_put(r, d)
+                               for k, r in rows.items()})
         jax.block_until_ready(blocks)
         _SHARDS.add(nd)
         return blocks
 
-    def _assemble(self, blocks: list) -> Dict[str, Any]:
-        """Per-device blocks → global ``jax.Array``s: quantized fields are
-        dequantized on each block's OWN device (a packed block reshaped back
-        to its rows there), then the blocks are declared as the addressable
-        shards of the ``(host_count * local_B, ...)`` global array. The
-        assembly itself is metadata-only — no gather, no cross-host
-        traffic."""
+    def _decode_blocks(self, blocks: List[Dict[str, Any]]) -> Dict[str, Any]:
+        """Decode every quantized block on its OWN device with the fused
+        kernel (uint8 in HBM → float out; DESIGN.md §12); jax
+        compiled-function execution is thread-safe. One device's blocks are
+        the batch. A mesh loader's are declared as the addressable shards of
+        ``(host_count * local_B, ...)`` global ``jax.Array``s (DESIGN.md
+        §15), which is metadata-only — no gather, no cross-host traffic."""
         jax = self._jax
-        quant = getattr(self.loader.ds, "quant", {})
+        dq = self._decoders()
+        for blk, d in zip(blocks, self._devices):
+            for k, q in dq.items():
+                if k in blk:
+                    blk[k] = q.decode(blk[k], d)
+        if not self.global_arrays:
+            return blocks[0]
+        if self._gsharding is None:
+            from ..distributed import data_mesh
+
+            self._gsharding = data_mesh.data_sharding()
         host_count = self.loader.mesh.host_count
         out: Dict[str, Any] = {}
         for k in blocks[0]:
             shards = [b[k] for b in blocks]
-            if k in quant:
-                shards = [self._decode(s, self._quant_params_on(d)[k])
-                          for s, d in zip(shards, self._gdevices)]
             gshape = (len(shards) * int(shards[0].shape[0]) * host_count,) + tuple(
                 shards[0].shape[1:])
             out[k] = jax.make_array_from_single_device_arrays(
@@ -344,15 +260,6 @@ class DeviceLoader:
     # ---- iteration ----------------------------------------------------------
     def __iter__(self) -> Iterator[Dict[str, Any]]:
         return self
-
-    def _dequant_on_device(self, moved: Dict[str, Any]) -> None:
-        """Decode quantized fields in place with the fused Pallas kernel
-        (uint8 in HBM → float out; DESIGN.md §12). Runs on the feeder
-        thread — dispatch and execution overlap the consumer's train step;
-        jax compiled-function execution is thread-safe."""
-        for f, p in self._quant_params_on(self.device).items():
-            if f in moved:
-                moved[f] = self._decode(moved[f], p)
 
     def __next__(self) -> Dict[str, Any]:
         if self._exc is not None:

@@ -6,11 +6,14 @@ Fusing them keeps the u8 bytes as the only HBM read (4x less traffic than
 convert-then-normalize materializing f32 in between).
 
 Grid: (row blocks, lane blocks) of a flattened (rows, C) view; tiles are
-sized by :func:`block_shape` so they fit the VMEM budget on a TPU.
+sized by :func:`block_shape` so they fit the VMEM budget on a TPU. A minor
+axis that is not a multiple of 128 lanes is viewed as :func:`packed_width`
+lanes where the element count allows.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Tuple
 
 import jax
@@ -24,6 +27,18 @@ from jax.experimental.pallas import tpu as pltpu
 VMEM_BUDGET = 8 << 20
 _SUBLANES_U8 = 32  # uint8 tile height: (32, 128)
 _LANES = 128
+
+
+def packed_width(shape: Tuple[int, ...]) -> int:
+    """Lanes of the packed ``(n / width, width)`` view in which the kernel
+    reads a u8 block of ``shape``: ``lcm(C, 128)`` for a minor axis ``C``
+    that is not a multiple of 128 when the element count ``n`` divides it,
+    else 0 (the kernel reads ``(n / C, C)``). The channel parameters are
+    tiled ``width / C`` times to match."""
+    c = int(shape[-1])
+    width = math.lcm(c, _LANES)
+    n = math.prod(shape)
+    return width if c % _LANES and n and n % width == 0 else 0
 
 
 def block_shape(rows: int, cols: int, out_dtype) -> Tuple[int, int]:

@@ -8,14 +8,13 @@ interpret mode is the validation vehicle).
 from __future__ import annotations
 
 import functools
-import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from .decode_attention import decode_attention_fwd
-from .dequant_u8 import block_shape, dequant_u8_fwd
+from .dequant_u8 import block_shape, dequant_u8_fwd, packed_width
 from .flash_attention import flash_attention_fwd
 from .ssd_scan import ssd_scan_fwd
 
@@ -70,14 +69,15 @@ def dequant_u8(x, scale, bias, *, out_dtype=jnp.float32, interpret: Optional[boo
     Tiles come from ``dequant_u8.block_shape``, so every caller of one
     shape (device feed, cold-start restore, its warmup) compiles the same
     kernel. A narrow last axis (C not a multiple of 128, e.g. RGB pixels)
-    is repacked to lcm(C, 128) lanes with the channel params tiled to
-    match, when the element count allows it; the result is unchanged."""
+    is repacked to ``dequant_u8.packed_width`` lanes with the channel params
+    tiled to match, when the element count allows it; the result is
+    unchanged."""
     shape = x.shape
     if x.size == 0:
         return jnp.zeros(shape, out_dtype)
     C = shape[-1]
-    width = math.lcm(C, 128)
-    if C % 128 and x.size % width == 0:
+    width = packed_width(shape)
+    if width:
         reps = width // C
         x2, scale, bias = x.reshape(-1, width), jnp.tile(scale, reps), jnp.tile(bias, reps)
     else:

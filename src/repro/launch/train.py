@@ -36,12 +36,6 @@ def main(argv=None) -> int:
         help="device-resident batch depth (default: RA_DEVICE_BUFS or 2)",
     )
     p.add_argument(
-        "--restore", choices=("pipelined", "naive"), default="pipelined",
-        help="--resume restore path (DESIGN.md §13): 'pipelined' overlaps "
-             "fetch/decode/dequant/H2D under the RA_COLDSTART_INFLIGHT "
-             "budget; 'naive' is the phase-by-phase baseline",
-    )
-    p.add_argument(
         "--mesh-hosts", default=None,
         help="data-mesh membership (DESIGN.md §15): comma-separated host "
              "names, one jax process per host, listed in process-index "
@@ -101,7 +95,6 @@ def main(argv=None) -> int:
                               moment_dtype=cfg.opt_moment_dtype),
         ),
         resume=not args.fresh,
-        restore_mode=args.restore,
     )
     print(f"done: steps={out['steps']} wall={out['wall_s']:.1f}s preempted={out['preempted']}")
     return 0

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..checkpoint import restore_naive, restore_pipelined
+from ..checkpoint import restore_pipelined
 from ..models.config import ModelConfig
 
 
@@ -26,7 +26,6 @@ class ServeEngine:
         params: Any = None,
         *,
         checkpoint: Optional[str] = None,
-        restore: str = "pipelined",
     ):
         self.model = model
         self.cfg: ModelConfig = model.cfg
@@ -35,14 +34,8 @@ class ServeEngine:
                 raise ValueError("need params or checkpoint")
             like = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
             # cold start is checkpoint-read latency: the overlapped restore
-            # engine (DESIGN.md §13) is the default; "naive" keeps the
-            # phase-by-phase baseline reachable for comparison
-            if restore == "pipelined":
-                params, _, _ = restore_pipelined(checkpoint, like)
-            elif restore == "naive":
-                params, _, _ = restore_naive(checkpoint, like)
-            else:
-                raise ValueError(f"restore must be 'pipelined' or 'naive', got {restore!r}")
+            # engine (DESIGN.md §13)
+            params, _, _ = restore_pipelined(checkpoint, like)
         self.params = params
         self._prefill = jax.jit(model.prefill)
         self._decode = jax.jit(model.decode_step, donate_argnums=(1,))

@@ -21,7 +21,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import numpy as np
 
-from ..checkpoint import CheckpointManager, restore_naive, restore_pipelined
+from ..checkpoint import CheckpointManager, restore_pipelined
 from ..data import DataLoader, LoaderState
 from ..distributed import optimizer as optim
 from ..models.config import ModelConfig
@@ -45,7 +45,6 @@ def train(
     *,
     step_fn: Optional[Callable] = None,
     resume: bool = True,
-    restore_mode: str = "pipelined",
     init_rng: int = 0,
     hooks: Optional[List[Callable[[int, Dict[str, float]], None]]] = None,
 ) -> Dict[str, Any]:
@@ -76,10 +75,8 @@ def train(
         # restore targets are shapes only: the device holds the state once
         params_like = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(init_rng)))
         opt_like = jax.eval_shape(lambda p: optim.init_state(p, adamw), params_like)
-        # overlapped cold-start restore straight to device (DESIGN.md §13);
-        # restore_mode="naive" keeps the phase-by-phase baseline reachable
-        restore_fn = restore_pipelined if restore_mode == "pipelined" else restore_naive
-        params, opt_state, extra = restore_fn(cm.path(s), params_like, opt_like)
+        # overlapped cold-start restore straight to device (DESIGN.md §13)
+        params, opt_state, extra = restore_pipelined(cm.path(s), params_like, opt_like)
         if "loader" in extra:
             loader.restore(LoaderState.from_dict(extra["loader"]))
         start_step = s

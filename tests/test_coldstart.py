@@ -150,6 +150,73 @@ def test_resharded_restore_onto_mesh(served, transport):
     assert pipe["w"].sharding.mesh == mesh
 
 
+def test_pipelined_restore_matches_the_kernel_reference(tmp_path):
+    """Leaf by leaf, a pipelined restore of a u8 checkpoint equals
+    ``ref.dequant_u8_ref`` of the stored codes: the side of
+    ``test_pipelined_matches_naive_*`` that shares no put or decode with
+    the restores. ``w`` (96x64) and ``inner/k`` (32x48) cross in the
+    kernel's packed view, ``inner/b`` (64,) in its own shape."""
+    from repro.core import obs
+    from repro.kernels import ref
+
+    tree = _tree(5)
+    p = save_checkpoint(str(tmp_path), 1, tree, quantize="u8")
+    with open(os.path.join(p, "manifest.json")) as f:
+        entries = json.load(f)["leaves"]
+    packed = obs.counter("device_loader.packed")
+    before = packed.value
+    got, _, _ = restore_pipelined(p, _like(tree))
+    assert packed.value - before == 2
+    for name, leaf in (("param__w", got["w"]), ("param__inner__b", got["inner"]["b"]),
+                       ("param__inner__k", got["inner"]["k"])):
+        codes = np.asarray(ra.read(os.path.join(p, entries[name]["file"])))
+        assert codes.dtype == np.uint8
+        c = codes.shape[-1]
+        scale, bias = ra.QuantInfo.from_dict(entries[name]["quant"]).channel_params(c)
+        want = ref.dequant_u8_ref(codes.reshape(-1, c), scale, bias).reshape(codes.shape)
+        assert leaf.shape == codes.shape and leaf.dtype == np.float32, name
+        np.testing.assert_allclose(np.asarray(leaf), np.asarray(want), rtol=1e-6, atol=1e-6,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("shape,sharded,on_device", [
+    ((8, 4), True, False),   # sharded: decoded on the host, then shard-put
+    ((), False, False),      # 0-d: decoded on the host
+    ((8, 4), False, True),   # one target device: u8 across, decoded there
+], ids=["sharded", "0-d", "device"])
+def test_leaf_router_decodes_on_the_host_or_the_device(monkeypatch, shape, sharded, on_device):
+    """One router decides where a quantized leaf is decoded, for both
+    restores, the budget and the warm-up; it puts the leaf accordingly and
+    the values are the host's dequantization either way."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro.checkpoint.coldstart import _decoded_on_device, _put_leaf
+    from repro.kernels import ops
+
+    quant = ra.QuantInfo(scale=np.float32(0.5), bias=np.float32(-1.0))
+    codes = (np.arange(int(np.prod(shape))) % 256).astype(np.uint8).reshape(shape)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    sh = NamedSharding(mesh, PartitionSpec("data", None)) if sharded else None
+    calls = []
+    real = ops.dequant_u8
+
+    def counted(x, *a, **kw):
+        calls.append(x.shape)
+        return real(x, *a, **kw)
+
+    monkeypatch.setattr(ops, "dequant_u8", counted)
+    assert _decoded_on_device(quant, sh, shape) is on_device
+    assert _decoded_on_device(None, sh, shape) is False
+    out, nbytes = _put_leaf(codes, quant, sh, None, None)
+    assert isinstance(out, jax.Array) and out.shape == shape
+    np.testing.assert_array_equal(np.asarray(out), quant.dequantize(codes))
+    assert calls == ([shape] if on_device else [])
+    assert nbytes == codes.size * (1 if on_device else 4)
+    if sharded:
+        assert out.sharding == sh
+
+
 def test_restore_resharded_rows_dequantize(tmp_path):
     tree = _tree(4)
     p = save_checkpoint(str(tmp_path), 1, tree, chunked=True, quantize="u8")

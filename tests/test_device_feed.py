@@ -377,7 +377,7 @@ def test_device_loader_packed_view_matches_host(pack_roots, case, reuse):
 
 
 def test_packed_width_is_the_kernels_repacking_rule():
-    from repro.data.device_loader import packed_width
+    from repro.kernels.dequant_u8 import packed_width
 
     assert packed_width((256, 224, 224, 3)) == 384      # (100352, 384)
     assert packed_width((8, 16, 16, 3)) == 384

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
-from repro.kernels.dequant_u8 import VMEM_BUDGET, block_shape
+from repro.kernels.dequant_u8 import VMEM_BUDGET, block_shape, packed_width
 
 
 @pytest.fixture(scope="module")
@@ -52,12 +52,8 @@ CASES = {
 
 def _kernel_view(shape):
     """The (rows, lanes) view ``ops.dequant_u8`` hands the kernel."""
-    c = shape[-1]
-    size = int(np.prod(shape))
-    width = np.lcm(c, 128)
-    if c % 128 and size % width == 0:
-        return size // width, int(width)
-    return size // c, c
+    width = packed_width(shape) or shape[-1]
+    return int(np.prod(shape)) // width, width
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
