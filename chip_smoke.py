@@ -124,7 +124,7 @@ def phase_train(model, workdir: str, seed: int, steps: int) -> dict:
     from repro.train import TrainLoopConfig, train
 
     ds = token_dataset(workdir, model.cfg, seed)
-    loader = DeviceLoader(DataLoader(RaDataset(ds), BATCH, seed=seed, reuse_buffers=True))
+    loader = DeviceLoader(DataLoader(RaDataset(ds), BATCH, seed=seed))
     out = train(
         model, loader,
         TrainLoopConfig(steps=steps, ckpt_every=steps, log_every=1, adamw=adamw(),

@@ -52,11 +52,13 @@ _join = ra.join_path
 # pool; a narrower one is cheaper to take in one vectorized fancy index,
 # which copies twice (into a temporary, then into the batch) (DESIGN.md §8).
 _DIRECT_ROW_BYTES = 64 << 10
-# Pool tasks those copies are split into. Copying into a fresh batch is
-# bound by faulting its pages in, which scales little with threads, while
-# every thread slows the feeder's host-to-device copy running beside it: on
-# a TPU v5e host, 256 ImageNet rows a batch took 23.8 ms in 8 tasks and
-# 28.8 ms in 2, with that copy at 42.9 and 41.5 ms, and 2 fed the chip more.
+# Pool tasks those copies are split into. Every thread slows the feeder's
+# host-to-device copy running beside it: on a TPU v5e host, 256 ImageNet rows
+# a batch took 23.8 ms in 8 tasks and 28.8 ms in 2, with that copy at 42.9
+# and 41.5 ms, and 2 fed the chip more. Those batches were fresh arrays, whose
+# copy is bound by faulting their pages in and scales little with threads;
+# the device feed now fills a reused ring slot, already faulted in, where the
+# count has not been measured again.
 _ROW_COPY_TASKS = 2
 
 # (row, field) pairs a gather served outside coalesced runs, by either path

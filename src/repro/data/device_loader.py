@@ -31,9 +31,15 @@ paper's read-bandwidth win evaporates at the device boundary.
   local devices), so the sharded train-step factories in
   ``repro.distributed.steps`` consume mesh batches unchanged.
 
-Safety: the feeder blocks until each transfer completes before pulling the
-next host batch, so the wrapped loader's ``reuse_buffers`` ring is never
-overwritten mid-copy; device batches are immutable ``jax.Array``s. The
+Safety: the wrapped loader streams host batches into its reused ring
+(``reuse_buffers``, turned on here: no consumer ever sees a host batch),
+and the feeder blocks until each transfer completes before pulling the
+next one, so a ring slot is never overwritten mid-copy. Where a target
+device's ``device_put`` may alias host memory — the CPU backend's can,
+zero-copy — each block is copied before its put, or the "device" batch
+would change under the consumer when the ring recycles
+(``may_alias_host``); a TPU's or GPU's put copies into device memory and
+reads the ring slot itself. Device batches are immutable ``jax.Array``s. The
 dequant kernel is dispatched on the feeder thread too — decode belongs to
 the feed pipeline, leaving the consumer's critical path as nothing but a
 queue pop and its train step (jax compiled-function execution is
@@ -50,7 +56,7 @@ from __future__ import annotations
 
 import queue
 import threading
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterator, List, Optional, Sequence
 
 import numpy as np
 
@@ -60,6 +66,16 @@ from .loader import DataLoader, LoaderState
 
 # device blocks put, one per target device a batch
 _SHARDS = obs.counter("device_loader.shards")
+# device blocks copied off the host batch before their put
+_DETACHED = obs.counter("device_loader.detached")
+
+
+def may_alias_host(devices: Sequence[Any]) -> bool:
+    """Whether a ``jax.device_put`` onto any of ``devices`` may alias the
+    host array it is given, so that a block of a reused host buffer must
+    be copied before its put: the CPU backend's put can hand the buffer
+    over zero-copy, while a TPU's or GPU's copies it into device memory."""
+    return any(d.platform == "cpu" for d in devices)
 
 
 def default_device_bufs() -> int:
@@ -106,6 +122,10 @@ class DeviceLoader:
         self._devices: Optional[List[Any]] = None  # lazy: the blocks' targets
         # device decode replaces host decode: raw uint8 over the wire
         loader.dequant = False
+        # host batches are staged in the loader's reused ring (the naive
+        # seed path has none): only the feeder sees them, and it finishes
+        # each transfer before it pulls the next batch
+        loader.reuse_buffers = not loader.naive
         self.bufs = max(1, bufs if bufs is not None else default_device_bufs())
         self.device = device
         self._interpret = interpret
@@ -134,12 +154,15 @@ class DeviceLoader:
         # so it can never steal batches from (or poison the sticky-error
         # state of) a restarted pipeline
         loader = self.loader
-
-        # device_put MAY alias host memory zero-copy (the CPU backend does
-        # for aligned arrays): with a reused staging ring the bytes must be
-        # detached first or the "device" batch changes under the consumer
-        # when the ring recycles
-        detach = bool(getattr(self.loader, "reuse_buffers", False))
+        jax = self._jax
+        if self._devices is None:
+            self._devices = jax.local_devices() if self.global_arrays else [self.device]
+        # device=None puts on jax's default device
+        default = jax.config.jax_default_device or jax.devices()[0]
+        if isinstance(default, str):  # a platform name
+            default = jax.devices(default)[0]
+        detach = loader.reuse_buffers and may_alias_host(
+            [default if d is None else d for d in self._devices])
 
         def run():
             while not stop.is_set():
@@ -197,11 +220,10 @@ class DeviceLoader:
         as u8 in the kernel's view (a block of rows is a contiguous slice,
         so a packed view is free): one ``device_loader.shard_put`` span per
         device around its puts, then one wait for every transfer, which
-        must COMPLETE before the staging ring may recycle. Returns one
+        must COMPLETE before the staging ring may recycle. With ``detach``
+        each block is copied before its put. Returns one
         ``{field: single-device array}`` per device, in device order."""
         jax = self._jax
-        if self._devices is None:
-            self._devices = jax.local_devices() if self.global_arrays else [self.device]
         nd = len(self._devices)
         dq = self._decoders()
         for k, v in batch.items():
@@ -225,6 +247,8 @@ class DeviceLoader:
                                for k, r in rows.items()})
         jax.block_until_ready(blocks)
         _SHARDS.add(nd)
+        if detach:
+            _DETACHED.add(nd)
         return blocks
 
     def _decode_blocks(self, blocks: List[Dict[str, Any]]) -> Dict[str, Any]:

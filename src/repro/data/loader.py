@@ -20,7 +20,8 @@
   no page-fault storm (DESIGN.md §8). The emitted arrays alias the ring, so
   a consumer must finish with (or copy) a batch before advancing more than
   ``prefetch + 1`` steps; that is exactly the train-loop pattern. Defaults to
-  off to preserve the seed's value semantics.
+  off to preserve the seed's value semantics; ``DeviceLoader`` turns it on,
+  as its consumer never sees a host batch.
 * **Straggler visibility**: the loader tracks wait-time (device starved) vs
   ready-time; exported in ``stats()`` for the train-loop straggler monitor.
 * **Remote datasets** (DESIGN.md §9): a loader over an ``http(s)://``
@@ -57,6 +58,9 @@ import numpy as np
 from ..core import obs
 from ..core.spec import RawArrayError
 from .dataset import RaDataset
+
+# batches produced into a slot of the reused ring
+_RING_FILLS = obs.counter("loader.ring_fills")
 
 
 @dataclass
@@ -381,6 +385,8 @@ class DataLoader:
                             buf = ring[pos % len(ring)]
                             pos += 1
                         b = self._produce(epoch, step, buf)
+                    if buf is not None:
+                        _RING_FILLS.add()
                     with self._stats_lock:
                         self._produce_s += sp.seconds
                 except Exception as e:  # surface in consumer (sticky there)

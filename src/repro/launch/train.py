@@ -75,11 +75,7 @@ def main(argv=None) -> int:
         from repro.distributed.data_mesh import DataMesh
 
         mesh = DataMesh.from_env()  # RA_MESH_HOSTS / RA_MESH_HOST, else None
-    # reuse_buffers is safe here: the train loop copies each batch to device
-    # (jnp.asarray) before requesting the next one; with --device-feed the
-    # DeviceLoader's feeder confirms each transfer before recycling the ring
-    loader = DataLoader(RaDataset(ds_root), args.batch, seed=args.seed,
-                        reuse_buffers=True, mesh=mesh)
+    loader = DataLoader(RaDataset(ds_root), args.batch, seed=args.seed, mesh=mesh)
     if args.device_feed:
         from repro.data import DeviceLoader
 

@@ -266,25 +266,43 @@ def test_host_loader_dequantizes_by_default(quant_root):
 
 
 # --------------------------------------------- DeviceLoader batch equivalence
-def _equiv(root, *, batches=4, batch=16, seed=7, **host_kw):
+def _equiv(root, *, batch=16, seed=7):
+    """``DeviceLoader`` turns on its wrapped loader's ring. Every batch it
+    delivers is held on the device through three laps of that ring and only
+    then compared with the host loader's, so a put that aliased a ring slot
+    (the CPU backend's can) would show as a changed batch. The ring takes
+    one fill a batch produced; on the CPU every block is copied before its
+    put."""
+    fills = obs.counter("loader.ring_fills")
+    detached = obs.counter("device_loader.detached")
+    inner = DataLoader(RaDataset(root), batch, seed=seed)
+    produce, into_ring = inner._produce, []
+
+    def produce_counted(epoch, step, out=None):
+        into_ring.append(out is not None)
+        return produce(epoch, step, out)
+
+    inner._produce = produce_counted
+    dev = DeviceLoader(inner)
+    assert inner.reuse_buffers
     host = DataLoader(RaDataset(root), batch, seed=seed)
-    dev = DeviceLoader(
-        DataLoader(RaDataset(root), batch, seed=seed, reuse_buffers=True,
-                   **host_kw)
-    )
+    f0, d0 = fills.value, detached.value
     try:
-        for _ in range(batches):
-            hb, db = next(host), next(dev)
-            assert hb["_state"].__dict__ == db["_state"].__dict__
-            for f in hb:
-                if f == "_state":
-                    continue
-                da = np.asarray(db[f])
-                assert da.dtype == hb[f].dtype
-                assert np.array_equal(da, hb[f]), f
+        held = [next(dev) for _ in range(3 * (inner._qcap + 2))]
+        want = [next(host) for _ in held]
     finally:
         host.stop()
         dev.stop()
+    for hb, db in zip(want, held):
+        assert hb["_state"].__dict__ == db["_state"].__dict__
+        for f in hb:
+            if f == "_state":
+                continue
+            da = np.asarray(db[f])
+            assert da.dtype == hb[f].dtype
+            assert np.array_equal(da, hb[f]), f
+    assert all(into_ring) and fills.value - f0 == len(into_ring) >= len(held)
+    assert detached.value - d0 == dev.stats()["h2d_batches"] >= len(held)
 
 
 def test_device_loader_matches_host_tokens(token_root):
@@ -295,6 +313,38 @@ def test_device_loader_matches_host_quantized(quant_root):
     """uint8 over the 'link' + interpret-mode Pallas dequant on CPU is
     bit-identical to the host numpy dequant (same float32 affine)."""
     _equiv(quant_root)
+
+
+def test_device_loader_leaves_the_naive_loader_without_a_ring(token_root):
+    """The seed-era ``naive`` path has no ring, so nothing is copied before
+    a put either."""
+    detached = obs.counter("device_loader.detached")
+    inner = DataLoader(RaDataset(token_root), 16, seed=7, naive=True)
+    dev = DeviceLoader(inner)
+    ref = DataLoader(RaDataset(token_root), 16, seed=7)
+    d0 = detached.value
+    try:
+        for _ in range(3):
+            assert np.array_equal(np.asarray(next(dev)["tokens"]), next(ref)["tokens"])
+    finally:
+        dev.stop()
+        ref.stop()
+    assert not inner.reuse_buffers and not inner._ring
+    assert detached.value == d0
+
+
+@pytest.mark.parametrize("platforms,copies", [
+    (("cpu",), True), (("tpu",), False), (("gpu",), False),
+    (("tpu",) * 4, False), (("tpu", "cpu"), True),
+])
+def test_detach_rule_follows_the_target_platform(platforms, copies):
+    """A block is copied before its put only where a target's put may alias
+    host memory: any CPU device among the targets."""
+    from types import SimpleNamespace
+
+    from repro.data.device_loader import may_alias_host
+
+    assert may_alias_host([SimpleNamespace(platform=p) for p in platforms]) is copies
 
 
 def test_device_loader_moves_quantized_bytes(quant_root):
@@ -352,12 +402,14 @@ def test_device_loader_packed_view_matches_host(pack_roots, case, reuse):
     packed and decodes to the host's dequantization bit for bit, in its
     logical shape; one that does not, or whose minor axis is 128 wide,
     crosses as it is. ``device_loader.packed`` counts one per batch put
-    in the first case and none in the others."""
+    in the first case and none in the others. The feed stages its host
+    batches in the ring whatever the wrapped loader was given."""
     shape, per_batch = _PACK_CASES[case]
     root = pack_roots[case]
     packed = obs.counter("device_loader.packed")
     host = DataLoader(RaDataset(root), 8, seed=11)
     dev = DeviceLoader(DataLoader(RaDataset(root), 8, seed=11, reuse_buffers=reuse))
+    assert dev.loader.reuse_buffers
     before = packed.value
     try:
         for _ in range(5):
